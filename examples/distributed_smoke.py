@@ -14,7 +14,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8 " + os.envir
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.checkpoint.manager import CheckpointManager  # noqa: E402
 from repro.common.sharding import set_activation_mesh  # noqa: E402
@@ -47,7 +47,7 @@ def build(mesh, cfg, opt_cfg):
 def main():
     cfg = get_lm_config("gemma3-1b", "smoke")
     opt_cfg = AdamWConfig(lr=1e-3, total_steps=20, warmup_steps=2)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     set_activation_mesh(mesh)
     print(f"mesh: {dict(mesh.shape)} over {len(jax.devices())} devices")
 

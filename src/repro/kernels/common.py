@@ -1,20 +1,21 @@
-"""Shared kernel plumbing: interpret-mode detection and tiling helpers."""
+"""Shared kernel plumbing: interpret-mode selection and tiling helpers."""
 from __future__ import annotations
 
 import jax
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def interpret_default() -> bool:
-    """Pallas kernels execute for real on TPU, in interpret mode elsewhere."""
-    return not on_tpu()
-
-
-def round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
+    """Pallas kernels compile for the TPU and run in the interpreter only
+    on the CPU (tests); any other platform is an error, never a silent
+    interpreter."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels support tpu (compiled) or cpu (interpreted), not {platform!r}"
+    )
 
 
 def pick_block(dim: int, preferred: int, align: int = 8) -> int:

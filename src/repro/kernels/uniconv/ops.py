@@ -20,7 +20,7 @@ def uniconv(
     stride: int = 1,
     *,
     block_l: int = 512,
-    block_n: int = 128,
+    block_n: int = 256,
 ) -> jax.Array:
     out = _kernel(
         x, w, hw, ksize,

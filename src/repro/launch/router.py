@@ -10,7 +10,10 @@ on SIGINT/SIGTERM or ``POST /shutdown``.
 
 The router process itself never imports jax — engines live only in the
 replica subprocesses — so the gateway stays responsive while replicas
-compile, crash or restart.
+compile, crash or restart.  Replica ``i`` is pinned to TPU chip ``i``
+(``repro.serving.router.one_chip_env``), so run at most as many
+replicas as the host has chips; ``--shards N > 1`` replicas are not
+pinned, since each needs a mesh of its own.
 
 Every replica is built from the **same** engine flags, including
 ``--seed``: identical weights plus the frontend's deterministic request
@@ -165,8 +168,12 @@ def main() -> None:
     run_dir = args.run_dir or tempfile.mkdtemp(prefix="sdacc-router-")
     os.makedirs(run_dir, exist_ok=True)
     cmd = replica_command(args)
+    # one chip per replica; a sharded replica needs the chips of its mesh
     replicas = [
-        ReplicaHandle(i, cmd, run_dir, spawn_timeout_s=args.spawn_timeout)
+        ReplicaHandle(
+            i, cmd, run_dir, spawn_timeout_s=args.spawn_timeout,
+            chip=i if args.shards == 1 else None,
+        )
         for i in range(args.replicas)
     ]
     router = ReplicaRouter(

@@ -77,6 +77,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_lm_config
 from repro.launch.steps import get_adapter
 from repro.models import unet as U
+from repro.runtime.device import configure_compile_cache, device_info
 from repro.serving import (
     EngineDriver,
     GenRequest,
@@ -481,6 +482,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    cache_dir = configure_compile_cache()
+    print(f"[serve] device {device_info()} compile cache {cache_dir}", flush=True)
     if args.http is not None:
         if args.mode != "diffusion":
             raise SystemExit("--http currently serves --mode diffusion only")

@@ -199,7 +199,6 @@ def apply_moe(cfg: LMConfig, p: Params, x: jax.Array) -> tuple[jax.Array, jax.Ar
         out, aux = grouped(x)
         return constrain_act(out), jnp.mean(aux)
 
-    from jax.experimental.shard_map import shard_map
 
     m_ax = "model" if ms > 1 else None
 
@@ -211,7 +210,7 @@ def apply_moe(cfg: LMConfig, p: Params, x: jax.Array) -> tuple[jax.Array, jax.Ar
         aux = jax.lax.pmean(jnp.mean(aux), ba)
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -222,6 +221,6 @@ def apply_moe(cfg: LMConfig, p: Params, x: jax.Array) -> tuple[jax.Array, jax.Ar
             P(None, m_ax, None),  # w_out: contraction dim sharded
         ),
         out_specs=(P(ba, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_in"], p["w_gate"], p["w_out"])
     return out, aux

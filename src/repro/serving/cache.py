@@ -734,7 +734,6 @@ def _make_sharded_insert(mesh):
     ``("data",)`` mesh, so each shard scatters its own captures into its
     own local slots — feature tensors never cross a shard boundary.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     lane = P("data")
@@ -746,11 +745,11 @@ def _make_sharded_insert(mesh):
             f_rf=cache.f_rf.at[slots].set(f_rf[lanes], mode="drop"),
         )
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(lane, lane, lane, lane, lane),
         out_specs=lane,
-        check_rep=False,
+        check_vma=False,
     )
 
     def insert(cache, f_sk, f_rf, lanes, slots):

@@ -46,6 +46,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.runtime.device import device_info
 from repro.serving.engine import CompletedRequest, GenRequest
 
 #: event names that end a request's stream
@@ -390,6 +391,7 @@ class EngineDriver:
             mode=eng._mode_name,
             lanes=eng.config.n_lanes,
             kernels=getattr(eng.config, "backend", "xla"),
+            device=device_info(),
             accepted=self.n_accepted,
             completed=self.n_completed,
             cancelled=self.n_cancelled,

@@ -267,8 +267,9 @@ class DiffusionEngine:
         self._state = LN.init_lanes(
             ucfg, config.n_lanes, config.max_steps, self.e_sk, self.e_rf
         )
+        self._params = params
         self._micro = LN.make_micro_step(
-            ucfg, self.dcfg, params, self.e_sk, self.e_rf,
+            ucfg, self.dcfg, self.e_sk, self.e_rf,
             cached=self.cache is not None, backend=config.backend,
         )
         self._admit = jax.jit(LN.admit, donate_argnums=(0,))
@@ -573,7 +574,7 @@ class DiffusionEngine:
                     else:
                         n_demoted_rf += 1
             self._state = self._micro(
-                self._state, jnp.int32(b_star), jnp.asarray(sel),
+                self._state, self._params, jnp.int32(b_star), jnp.asarray(sel),
                 jnp.asarray(feat_src), jnp.asarray(feat_dist), self.cache.state,
             )
             if b_star == SM.FULL:
@@ -604,7 +605,9 @@ class DiffusionEngine:
                 if taken:
                     self.cache.insert_many(self._state.f_sk, self._state.f_rf, lanes, slots)
         else:
-            self._state = self._micro(self._state, jnp.int32(b_star), jnp.asarray(sel))
+            self._state = self._micro(
+                self._state, self._params, jnp.int32(b_star), jnp.asarray(sel)
+            )
 
         self._lane_step[sel] += 1
         self._stall[active] += 1

@@ -169,6 +169,21 @@ def run_straight_line(params: dict[str, Any] | None = None) -> dict[int, np.ndar
     return out
 
 
+#: the JAX version a golden file was written under: seeded weights and
+#: noise change across JAX releases, so a file holds only for its own
+VERSION_KEY = "jax_version"
+
+
+def check_version(z, path: str) -> None:
+    """Refuse a golden file written under another JAX release."""
+    written = str(z[VERSION_KEY]) if VERSION_KEY in z.files else "an unrecorded version"
+    if written != jax.__version__:
+        raise RuntimeError(
+            f"{path} was written under jax {written}, this is jax {jax.__version__}: "
+            "regenerate it with tools/regen_golden_latents.py / tools/regen_golden_scenarios.py"
+        )
+
+
 def save_golden(path: str) -> tuple[dict[int, np.ndarray], dict[int, np.ndarray]]:
     """Regenerate the golden file (both execution families) -> (line, engine)."""
     params = golden_params()
@@ -176,7 +191,7 @@ def save_golden(path: str) -> tuple[dict[int, np.ndarray], dict[int, np.ndarray]
     engine = run_engine(params, cache_mode="off")
     arrays = {f"line_rid{rid}": lat for rid, lat in line.items()}
     arrays |= {f"engine_rid{rid}": lat for rid, lat in engine.items()}
-    np.savez_compressed(path, **arrays)
+    np.savez_compressed(path, **arrays, **{VERSION_KEY: np.asarray(jax.__version__)})
     return line, engine
 
 
@@ -184,7 +199,10 @@ def load_golden(path: str) -> tuple[dict[int, np.ndarray], dict[int, np.ndarray]
     """Load the golden file -> ({rid: straight-line}, {rid: engine})."""
     line, engine = {}, {}
     with np.load(path) as z:
+        check_version(z, path)
         for k in z.files:
+            if k == VERSION_KEY:
+                continue
             fam, rid = k.rsplit("_rid", 1)
             (line if fam == "line" else engine)[int(rid)] = z[k]
     return line, engine

@@ -210,7 +210,6 @@ def gather_latent(state: LaneState, lane: int) -> jax.Array:
 def make_micro_step(
     ucfg: UNetConfig,
     dcfg: DiffusionConfig,
-    params: Params,
     e_sk: int,
     e_rf: int,
     *,
@@ -230,11 +229,11 @@ def make_micro_step(
     a lane's planned FULL step to SKETCH, which the device-side plan alone
     cannot see.
 
-    ``cached=False`` — signature ``(state, b_star, sel)``: partial branches
+    ``cached=False`` — signature ``(state, params, b_star, sel)``: partial branches
     consume the lane's own captured features (the PR 1 behaviour).
 
-    ``cached=True`` — signature ``(state, b_star, sel, feat_src, feat_dist,
-    cache)``: ``feat_src`` is a per-lane int32 slot index into the
+    ``cached=True`` — signature ``(state, params, b_star, sel, feat_src,
+    feat_dist, cache)``: ``feat_src`` is a per-lane int32 slot index into the
     device-resident feature cache (-1 = own features) and ``feat_dist`` the
     probed slot's prompt-signature distance; the slot is consumed only
     where ``feat_dist`` is *strictly* below the lane's per-step threshold
@@ -258,7 +257,9 @@ def make_micro_step(
 
     ``backend`` selects the kernel backend (``repro.models.backend``) for
     every U-Net invocation; it is resolved once here and captured in the
-    jitted closure — never a traced value.
+    jitted closure — never a traced value.  The weights, by contrast, are
+    an argument and never a closure constant: folded into the program, an
+    sd_v14 U-Net's 1.7 GB of weights are copied through every compile.
     """
     from repro.models.backend import resolve_backend
 
@@ -269,6 +270,7 @@ def make_micro_step(
 
     def _body(
         state: LaneState,
+        params: Params,
         b_star: jax.Array,
         sel: jax.Array,  # [N] bool host-computed advance mask
         entry_sk: jax.Array,  # [2N, ...] features the SKETCH branch consumes
@@ -343,13 +345,16 @@ def make_micro_step(
 
     if not cached:
 
-        def micro_step(state: LaneState, b_star: jax.Array, sel: jax.Array) -> LaneState:
-            return _body(state, b_star, sel, state.f_sk, state.f_rf)
+        def micro_step(
+            state: LaneState, params: Params, b_star: jax.Array, sel: jax.Array
+        ) -> LaneState:
+            return _body(state, params, b_star, sel, state.f_sk, state.f_rf)
 
         return jax.jit(micro_step, donate_argnums=(0,))
 
     def micro_step_cached(
         state: LaneState,
+        params: Params,
         b_star: jax.Array,
         sel: jax.Array,
         feat_src: jax.Array,  # [N] int32 cache slot per lane, -1 = own
@@ -363,7 +368,7 @@ def make_micro_step(
         use = (feat_src >= 0) & (feat_dist < thr_t)
         entry_sk = select_entry_features(state.f_sk, cache.f_sk, feat_src, use)
         entry_rf = select_entry_features(state.f_rf, cache.f_rf, feat_src, use)
-        return _body(state, b_star, sel, entry_sk, entry_rf)
+        return _body(state, params, b_star, sel, entry_sk, entry_rf)
 
     return jax.jit(micro_step_cached, donate_argnums=(0,))
 
@@ -570,7 +575,6 @@ def make_sharded_micro_step(
     ``backend`` selects the kernel backend for every U-Net invocation,
     resolved once at build time exactly as in :func:`make_micro_step`.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.models.backend import resolve_backend
@@ -656,11 +660,11 @@ def make_sharded_micro_step(
             entry_sk, entry_rf = state.f_sk, state.f_rf
             return local_body(params, state, b_arr, sel, entry_sk, entry_rf)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(repl, lane, lane, lane),
             out_specs=lane,
-            check_rep=False,
+            check_vma=False,
         )
 
         def micro_step(state, params, b_arr, sel):
@@ -676,11 +680,11 @@ def make_sharded_micro_step(
         entry_rf = _select_local(state.f_rf, cache.f_rf, feat_src, use)
         return local_body(params, state, b_arr, sel, entry_sk, entry_rf)
 
-    mapped_cached = shard_map(
+    mapped_cached = jax.shard_map(
         shard_body_cached, mesh=mesh,
         in_specs=(repl, lane, lane, lane, lane, lane, lane),
         out_specs=lane,
-        check_rep=False,
+        check_vma=False,
     )
 
     def micro_step_cached(state, params, b_arr, sel, feat_src, feat_dist, cache):

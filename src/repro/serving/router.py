@@ -81,6 +81,7 @@ REPLICA_STAT_KEYS = (
     "cache_probe_hits", "cache_evictions", "kernels", "mode",
     "hbm_hits", "spill_promotions", "gossip_routed",
     "cache_spill_demotions", "cache_spill_promotions", "cache_spill_entries",
+    "device",
 )
 
 #: fleet counters summed across replicas in the router's ``/stats``
@@ -211,6 +212,18 @@ def pick_replica(
 # ---------------------------------------------------------------------------
 
 
+def one_chip_env(chip: int) -> dict[str, str]:
+    """Environment that gives a replica process TPU chip ``chip`` and no
+    other, so N replicas on one host each load libtpu for their own chip
+    (other platforms ignore these variables)."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(8476 + chip),
+    }
+
+
 class ReplicaHandle:
     """One supervised server-replica subprocess.
 
@@ -219,6 +232,8 @@ class ReplicaHandle:
     consecutive probe failures) and the router-side load/warmth state
     (``inflight`` routed weight, last published ``/stats``).  States:
     ``down`` → ``starting`` → ``ready`` → (``draining`` →) ``down``.
+    ``chip`` pins every generation of the process to that TPU chip
+    (:func:`one_chip_env`); None leaves device visibility alone.
     """
 
     def __init__(
@@ -230,8 +245,10 @@ class ReplicaHandle:
         host: str = "127.0.0.1",
         spawn_timeout_s: float = 300.0,
         backoff: RestartBackoff | None = None,
+        chip: int | None = None,
     ):
         self.idx = idx
+        self.chip = chip
         self.cmd = list(cmd)
         self.run_dir = run_dir
         self.host = host
@@ -297,10 +314,12 @@ class ReplicaHandle:
         )
         self._close_log()
         self._log_file = open(os.path.join(self.run_dir, f"replica{self.idx}.log"), "ab")
+        env = None if self.chip is None else dict(os.environ, **one_chip_env(self.chip))
         self.proc = subprocess.Popen(
             self.cmd + ["--port-file", self._port_file],
             stdout=self._log_file,
             stderr=subprocess.STDOUT,
+            env=env,
         )
 
     def _close_log(self) -> None:

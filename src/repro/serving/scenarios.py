@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -43,6 +44,8 @@ from repro.serving.golden import (
     MAX_STEPS,
     N_LANES,
     UCFG,
+    VERSION_KEY,
+    check_version,
     golden_params,
 )
 
@@ -238,7 +241,7 @@ def save_golden(path: str) -> tuple[dict[str, np.ndarray], dict[str, np.ndarray]
     engine = run_engine(params, cache_mode="off")
     arrays = {f"line_{name}": lat for name, lat in line.items()}
     arrays |= {f"engine_{name}": lat for name, lat in engine.items()}
-    np.savez_compressed(path, **arrays)
+    np.savez_compressed(path, **arrays, **{VERSION_KEY: np.asarray(jax.__version__)})
     return line, engine
 
 
@@ -246,7 +249,10 @@ def load_golden(path: str) -> tuple[dict[str, np.ndarray], dict[str, np.ndarray]
     """Load the scenarios golden file -> ({name: line}, {name: engine})."""
     line, engine = {}, {}
     with np.load(path) as z:
+        check_version(z, path)
         for k in z.files:
+            if k == VERSION_KEY:
+                continue
             fam, name = k.split("_", 1)
             (line if fam == "line" else engine)[name] = z[k]
     return line, engine

@@ -3,10 +3,7 @@ compression error-feedback property."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # CI installs hypothesis; bare runs degrade to skips
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.pipeline import DataConfig, Prefetcher, latent_batch, token_batch
 from repro.optim import (

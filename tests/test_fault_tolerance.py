@@ -1,10 +1,7 @@
 """Fault-tolerance runtime: stragglers, elastic re-mesh, resume loop."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # CI installs hypothesis; bare runs degrade to skips
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.runtime.fault_tolerance import (

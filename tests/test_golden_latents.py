@@ -50,6 +50,23 @@ def test_golden_file_families_cross_check(golden):
         np.testing.assert_allclose(line[rid], engine[rid], atol=2e-4)
 
 
+@pytest.mark.parametrize("harness", ["golden", "scenarios"])
+def test_golden_file_written_under_another_jax_is_refused(tmp_path, harness):
+    """Seeded weights change across JAX releases, so each file records the
+    release it was written under and loading it anywhere else fails."""
+    from repro.serving import scenarios as S
+
+    mod = G if harness == "golden" else S
+    path = os.path.join(REPO, "tests", "golden", mod.GOLDEN_FILE)
+    with np.load(path) as z:
+        assert str(z[G.VERSION_KEY]) == G.jax.__version__
+        arrays = dict(z)
+    stale = tmp_path / "stale.npz"
+    np.savez(stale, **(arrays | {G.VERSION_KEY: np.asarray("0.4.37")}))
+    with pytest.raises(RuntimeError, match="written under jax 0.4.37"):
+        mod.load_golden(str(stale))
+
+
 def test_all_paths_bit_exact_vs_golden_file():
     """Subprocess under the canonical XLA env: straight-line sampler, engine
     with cache off, and engine at threshold 0 must reproduce the checked-in

@@ -108,14 +108,14 @@ def test_distributed_train_step_8dev_subprocess():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.common.sharding import set_activation_mesh
 from repro.configs import get_lm_config
 from repro.launch.steps import get_adapter, make_train_step, opt_pspecs
 from repro.optim import AdamWConfig, init_adamw
 
 cfg = get_lm_config("yi-6b", "smoke")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 set_activation_mesh(mesh)
 ad = get_adapter(cfg)
 pspecs = ad.pspecs(2)

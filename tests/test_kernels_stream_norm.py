@@ -56,6 +56,8 @@ def test_stream_norm_block_m_invariance():
 GN_CASES = [
     # (b, l, c, groups) — includes the served sd_toy shapes (groups=8)
     (2, 256, 32, 8), (2, 64, 64, 8), (1, 16, 128, 8), (3, 100, 24, 4),
+    # sd_v14 level 1: statistics accumulate over 8 row tiles
+    (1, 4096, 640, 32),
 ]
 
 

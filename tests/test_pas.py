@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # CI installs hypothesis; bare runs degrade to skips
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.common.types import DiffusionConfig, PASPlan, UNetConfig
 from repro.configs import get_unet_config

@@ -1,9 +1,6 @@
 """Phase division (Eq. 2) and shift-score machinery (Eq. 1)."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # CI installs hypothesis; bare runs degrade to skips
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import phase_division as PD
 from repro.core import shift_score as SS

@@ -1,9 +1,6 @@
 """Adaptive reuse & fusion planner (Sec. V): invariants + paper ablation."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # CI installs hypothesis; bare runs degrade to skips
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_unet_config
 from repro.core import reuse_planner as RP

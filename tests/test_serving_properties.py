@@ -21,10 +21,7 @@ Random traces come in two flavours: seeded numpy cases that always run
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # CI installs hypothesis; bare runs degrade to skips
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.scheduler import CacheAwareScheduler, FIFOScheduler, PlanAwareScheduler
 

@@ -23,6 +23,7 @@ import pytest
 from repro.runtime.fault_tolerance import RestartBackoff
 from repro.serving.router import (
     ReplicaHandle,
+    one_chip_env,
     payload_warmth,
     pick_replica,
     request_signature,
@@ -302,6 +303,31 @@ def test_replica_handle_backoff_resets_on_ready():
     assert [h.backoff.next_delay() for _ in range(4)] == [1.0, 2.0, 4.0, 8.0]
     h.backoff.reset()
     assert h.backoff.next_delay() == 1.0
+
+
+@pytest.mark.parametrize("chip", [None, 3])
+def test_replica_spawn_pins_its_chip(tmp_path, chip):
+    """A pinned replica process sees exactly its own chip in the TPU
+    environment; an unpinned one inherits the parent's."""
+    code = (
+        "import os, sys; open(sys.argv[2] + '.env', 'w').write("
+        "os.environ.get('TPU_VISIBLE_CHIPS', '-') + ' ' + "
+        "os.environ.get('TPU_CHIPS_PER_PROCESS_BOUNDS', '-'))"
+    )
+    env_before = dict(os.environ)
+    h = ReplicaHandle(0, [sys.executable, "-c", code], str(tmp_path), chip=chip)
+    h.spawn()
+    assert h.proc.wait(60) == 0
+    h._close_log()
+    with open(h._port_file + ".env") as f:
+        seen = f.read()
+    inherited = os.environ.get("TPU_VISIBLE_CHIPS", "-"), os.environ.get(
+        "TPU_CHIPS_PER_PROCESS_BOUNDS", "-"
+    )
+    assert seen == ("3 1,1,1" if chip == 3 else " ".join(inherited))
+    assert dict(os.environ) == env_before  # the parent's environment is untouched
+    if chip is not None:
+        assert one_chip_env(chip)["TPU_VISIBLE_CHIPS"] == str(chip)
 
 
 # ---------------------------------------------------------------------------

@@ -7,10 +7,7 @@ and the flash-attention kernel.
 import math
 
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # CI installs hypothesis; bare runs degrade to skips
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 def online_softmax_denominator(x: np.ndarray, tile: int) -> tuple[float, float]:

@@ -1,0 +1,6 @@
+"""Percent of the traced window in which no operation ran on the device
+(one minus the union of operation intervals, averaged over chips)."""
+
+
+def read(run):
+    return None if run.trace is None else 100.0 * run.trace.idle_share

@@ -420,13 +420,14 @@ class EngineDriver:
                 t = self._tickets.get(rid)
             if t is None:  # cancelled while still in the inbox
                 return
-            self.engine.submit(t.req)
-            self._emit(rid, {
-                "event": "queued", "rid": rid,
-                "quality": t.req.quality_tier,
-                "kernels": getattr(self.engine.config, "backend", "xla"),
-                "pending": self.engine.n_pending, "active": self.engine.n_active,
-            })
+            with self.engine.metrics.span("driver.submit", rid=rid):
+                self.engine.submit(t.req)
+                self._emit(rid, {
+                    "event": "queued", "rid": rid,
+                    "quality": t.req.quality_tier,
+                    "kernels": getattr(self.engine.config, "backend", "xla"),
+                    "pending": self.engine.n_pending, "active": self.engine.n_active,
+                })
         elif kind == "cancel":
             rid = msg[1]
             with self._lock:
@@ -455,10 +456,15 @@ class EngineDriver:
 
     def _pump_inbox(self, block: bool) -> None:
         if block:
+            # the engine holds no work: the wait is the ``driver.wait`` span
+            metrics = self.engine.metrics
+            metrics.mark_idle()
             try:
-                self._handle(self._inbox.get(timeout=self.idle_wait_s))
+                with metrics.span("driver.wait"):
+                    msg = self._inbox.get(timeout=self.idle_wait_s)
             except queue.Empty:
                 return
+            self._handle(msg)
         while True:
             try:
                 self._handle(self._inbox.get_nowait())
@@ -517,9 +523,10 @@ class EngineDriver:
                         break
                     continue
                 done = eng.step(now_s=self._clock(), clock=self._clock)
-                self._announce_progress()
-                for c in done:
-                    self._finish(c)
+                with eng.metrics.span("driver.events"):
+                    self._announce_progress()
+                    for c in done:
+                        self._finish(c)
         except BaseException as err:  # engine failure: fail every open stream
             self._fail_open(err)
             self._final_summary = dict(self._snapshot(), error=repr(err))

@@ -34,7 +34,7 @@ from repro.models import unet as U
 from repro.models import vae as V
 from repro.serving import lanes as LN
 from repro.serving.cache import FeatureCache, ShardedFeatureCache, prompt_signature
-from repro.serving.metrics import ServingMetrics
+from repro.serving.metrics import CLASSES, ServingMetrics
 from repro.serving.policy import ResolvedPolicy
 from repro.serving.scheduler import FIFOScheduler
 
@@ -447,7 +447,15 @@ class DiffusionEngine:
             req = self.scheduler.next_request(self._remaining_branches())
             if req is None:
                 return
-            self._prefetch_spill(req)
+            self._admit_lane(lane, req, now_s)
+
+    def _admit_lane(self, lane: int, req: GenRequest, now_s: float,
+                    shard: int | None = None) -> None:
+        """Put ``req`` into the empty ``lane`` (of ``shard``, for the
+        sharded engine): spill prefetch, lane tensors to the device, the
+        admit program, host mirrors."""
+        with self.metrics.span("engine.admit", rid=req.rid, lane=lane):
+            self._prefetch_spill(req, shard)
             lp = req._lane_plan
             mask, x_init, noise0 = self._admit_extras(req)
             self._state = self._admit(
@@ -462,10 +470,10 @@ class DiffusionEngine:
                 jnp.asarray(lp.thr),
                 mask, x_init, noise0,
             )
-            self._lane_req[lane] = req
-            self._lane_step[lane] = 0
-            self._lane_admit_s[lane] = now_s
-            self._stall[lane] = 0
+        self._lane_req[lane] = req
+        self._lane_step[lane] = 0
+        self._lane_admit_s[lane] = now_s
+        self._stall[lane] = 0
 
     def _probe_eligible(self, req: GenRequest, lane: int, planned: int) -> bool:
         """Whether a lane's next planned step may be served from the cache.
@@ -519,95 +527,80 @@ class DiffusionEngine:
 
         ``clock`` (same origin as ``now_s``) re-reads the time *after* the
         retirement device sync so completion stamps include the queued
-        async compute; without it ``now_s`` is used as-is.
+        async compute; without it ``now_s`` is used as-is.  The whole call
+        is the ``engine.tick`` span.
         """
+        with self.metrics.span("engine.tick"):
+            return self._tick(now_s, clock)
+
+    def _tick(self, now_s: float, clock: Callable[[], float] | None) -> list[CompletedRequest]:
         self._backfill(now_s)
         active = self._active_lanes()
         if not active:
             return []
-        t_step0 = time.perf_counter()
 
-        planned = np.array(
-            [self._lane_req[i]._lane_plan.branches[self._lane_step[i]] for i in active],
-            np.int64,
-        )
-        # cache demotion: a planned FULL step with a warm, close-enough slot
-        # executes as SKETCH consuming the cached features of another (or an
-        # earlier) FULL step; a planned SKETCH step whose quality policy
-        # allows it demotes one further, to REFINE on the slot's refine
-        # features.  The packing policy votes over the *effective* classes
-        # so demoted lanes amortize with cheaper planned lanes.
-        hit_slots = self._probe_cache(active, planned)
-        planned_of = {int(lane): int(planned[k]) for k, lane in enumerate(active)}
-        effective = planned.copy()
-        for k, lane in enumerate(active):
-            if lane in hit_slots:
-                effective[k] = SM.SKETCH if planned[k] == SM.FULL else SM.REFINE
-        b_star = self.scheduler.pick_branch(effective, self._stall[active])
+        with self.metrics.span("engine.plan"):
+            planned = np.array(
+                [self._lane_req[i]._lane_plan.branches[self._lane_step[i]] for i in active],
+                np.int64,
+            )
+            # cache demotion: a planned FULL step with a warm, close-enough
+            # slot executes as SKETCH consuming the cached features of
+            # another (or an earlier) FULL step; a planned SKETCH step whose
+            # quality policy allows it demotes one further, to REFINE on the
+            # slot's refine features.  The packing policy votes over the
+            # *effective* classes so demoted lanes amortize with cheaper
+            # planned lanes.
+            hit_slots = self._probe_cache(active, planned)
+            planned_of = {int(lane): int(planned[k]) for k, lane in enumerate(active)}
+            effective = planned.copy()
+            for k, lane in enumerate(active):
+                if lane in hit_slots:
+                    effective[k] = SM.SKETCH if planned[k] == SM.FULL else SM.REFINE
+            b_star = self.scheduler.pick_branch(effective, self._stall[active])
 
-        # the advance mask is deterministic from the host-known plans +
-        # cache metadata — mirror it here instead of syncing on the device
-        # (keeps dispatch async)
-        sel = np.zeros((self.config.n_lanes,), bool)
-        advanced = np.asarray(active)[effective == b_star]
-        sel[advanced] = True
-        n_demoted = n_demoted_rf = 0
-        if self.cache is not None:
-            feat_src = np.full((self.config.n_lanes,), -1, np.int32)
-            feat_dist = np.full((self.config.n_lanes,), np.inf, np.float32)
-            if b_star in (SM.SKETCH, SM.REFINE):
-                for lane in advanced:
-                    hit = hit_slots.get(int(lane))
-                    if hit is None:
-                        # a planned (un-demoted) partial step that probed
-                        # and missed settles its accounting here
-                        req = self._lane_req[lane]
-                        if self._probe_eligible(req, int(lane), planned_of[int(lane)]):
-                            self.cache.note_miss()
-                        continue
-                    slot, dist = hit
-                    feat_src[lane] = slot
-                    feat_dist[lane] = dist
-                    self.cache.note_hit(slot)
-                    if planned_of[int(lane)] == SM.FULL:
-                        n_demoted += 1
-                    else:
-                        n_demoted_rf += 1
-            self._state = self._micro(
-                self._state, self._params, jnp.int32(b_star), jnp.asarray(sel),
-                jnp.asarray(feat_src), jnp.asarray(feat_dist), self.cache.state,
-            )
-            if b_star == SM.FULL:
-                # fresh captures become warm slots: reserve host-side
-                # (conflict-free within the batch), then fill every slot in
-                # one batched device scatter (padded to n_lanes so the
-                # scatter compiles once)
-                lanes = np.zeros((self.config.n_lanes,), np.int32)
-                slots = np.full((self.config.n_lanes,), self.cache.n_slots, np.int32)
-                taken: set[int] = set()
-                for k, lane in enumerate(advanced):
-                    req = self._lane_req[lane]
-                    t = int(req._lane_plan.ts[self._lane_step[lane]])
-                    if req.allow_cache and self._lane_step[lane] >= self.config.cache_min_step:
-                        self.cache.note_miss()  # probed FULL executed as FULL
-                    if self.config.cache_mode == "intra" and not req.allow_cache:
-                        # only this request could ever consume the capture,
-                        # and it opted out — don't evict useful slots for it
-                        continue
-                    slot = self.cache.reserve(
-                        t, req._sig, req.rid, exclude=taken, offset=req.sched_offset
-                    )
-                    if slot is None:  # ring smaller than the FULL batch
-                        continue
-                    taken.add(slot)
-                    lanes[k] = int(lane)
-                    slots[k] = slot
-                if taken:
-                    self.cache.insert_many(self._state.f_sk, self._state.f_rf, lanes, slots)
-        else:
-            self._state = self._micro(
-                self._state, self._params, jnp.int32(b_star), jnp.asarray(sel)
-            )
+            # the advance mask is deterministic from the host-known plans +
+            # cache metadata — mirror it here instead of syncing on the
+            # device (keeps dispatch async)
+            sel = np.zeros((self.config.n_lanes,), bool)
+            advanced = np.asarray(active)[effective == b_star]
+            sel[advanced] = True
+            n_demoted = n_demoted_rf = 0
+            if self.cache is not None:
+                feat_src = np.full((self.config.n_lanes,), -1, np.int32)
+                feat_dist = np.full((self.config.n_lanes,), np.inf, np.float32)
+                if b_star in (SM.SKETCH, SM.REFINE):
+                    for lane in advanced:
+                        hit = hit_slots.get(int(lane))
+                        if hit is None:
+                            # a planned (un-demoted) partial step that probed
+                            # and missed settles its accounting here
+                            req = self._lane_req[lane]
+                            if self._probe_eligible(req, int(lane), planned_of[int(lane)]):
+                                self.cache.note_miss()
+                            continue
+                        slot, dist = hit
+                        feat_src[lane] = slot
+                        feat_dist[lane] = dist
+                        self.cache.note_hit(slot)
+                        if planned_of[int(lane)] == SM.FULL:
+                            n_demoted += 1
+                        else:
+                            n_demoted_rf += 1
+
+        with self.metrics.span("engine.dispatch", cls=CLASSES[b_star], lanes=len(advanced)):
+            self.metrics.mark_dispatch()
+            if self.cache is None:
+                self._state = self._micro(
+                    self._state, self._params, jnp.int32(b_star), jnp.asarray(sel)
+                )
+            else:
+                self._state = self._micro(
+                    self._state, self._params, jnp.int32(b_star), jnp.asarray(sel),
+                    jnp.asarray(feat_src), jnp.asarray(feat_dist), self.cache.state,
+                )
+                if b_star == SM.FULL:
+                    self._insert_captures(advanced)
 
         self._lane_step[sel] += 1
         self._stall[active] += 1
@@ -621,30 +614,66 @@ class DiffusionEngine:
             n_demoted=n_demoted, n_demoted_refine=n_demoted_rf,
         )
 
+        return self._retire(active, now_s, clock)
+
+    def _insert_captures(self, advanced: np.ndarray) -> None:
+        """Fresh FULL captures become warm slots: reserve host-side
+        (conflict-free within the batch), then fill every slot in one
+        batched device scatter (padded to n_lanes so the scatter compiles
+        once)."""
+        lanes = np.zeros((self.config.n_lanes,), np.int32)
+        slots = np.full((self.config.n_lanes,), self.cache.n_slots, np.int32)
+        taken: set[int] = set()
+        for k, lane in enumerate(advanced):
+            req = self._lane_req[lane]
+            t = int(req._lane_plan.ts[self._lane_step[lane]])
+            if req.allow_cache and self._lane_step[lane] >= self.config.cache_min_step:
+                self.cache.note_miss()  # probed FULL executed as FULL
+            if self.config.cache_mode == "intra" and not req.allow_cache:
+                # only this request could ever consume the capture, and it
+                # opted out — don't evict useful slots for it
+                continue
+            slot = self.cache.reserve(
+                t, req._sig, req.rid, exclude=taken, offset=req.sched_offset
+            )
+            if slot is None:  # ring smaller than the FULL batch
+                continue
+            taken.add(slot)
+            lanes[k] = int(lane)
+            slots[k] = slot
+        if taken:
+            self.cache.insert_many(self._state.f_sk, self._state.f_rf, lanes, slots)
+
+    def _retire(
+        self, active: list[int], now_s: float, clock: Callable[[], float] | None
+    ) -> list[CompletedRequest]:
+        """Read back, release and report every lane that finished its
+        schedule; each is one ``engine.retire`` span."""
         done: list[CompletedRequest] = []
         for lane in active:
             req = self._lane_req[lane]
             if self._lane_step[lane] < req.timesteps:
                 continue
-            latent = self._state.x[lane]
-            image = None
-            if self._decoder is not None:
-                image = np.asarray(self._decoder(latent[None])[0])
-            latent = np.asarray(latent)  # syncs the queued micro-steps
-            done.append(
-                CompletedRequest(
-                    rid=req.rid,
-                    latent=latent,
-                    image=image,
-                    submitted_s=req.arrival_s,
-                    admitted_s=self._lane_admit_s[lane],
-                    completed_s=clock() if clock is not None else now_s,
+            with self.metrics.span("engine.retire", rid=req.rid):
+                latent = self._state.x[lane]
+                image = None
+                if self._decoder is not None:
+                    image = np.asarray(self._decoder(latent[None])[0])
+                latent = np.asarray(latent)  # syncs the queued micro-steps
+                self.metrics.mark_sync()
+                done.append(
+                    CompletedRequest(
+                        rid=req.rid,
+                        latent=latent,
+                        image=image,
+                        submitted_s=req.arrival_s,
+                        admitted_s=self._lane_admit_s[lane],
+                        completed_s=clock() if clock is not None else now_s,
+                    )
                 )
-            )
-            self._release_lane(lane)
+                self._release_lane(lane)
             self._lane_req[lane] = None
             self.metrics.record_completion(done[-1].latency_s, done[-1].queue_wait_s)
-        self.metrics.record_step_time(self.config.backend, time.perf_counter() - t_step0)
         return done
 
     def run(
@@ -841,25 +870,7 @@ class ShardedDiffusionEngine(DiffusionEngine):
             )
             if req is None:
                 return
-            self._prefetch_spill(req, shard)
-            lp = req._lane_plan
-            mask, x_init, noise0 = self._admit_extras(req)
-            self._state = self._admit(
-                self._state,
-                jnp.int32(lane),
-                jnp.asarray(req._entry),
-                jnp.asarray(req.ctx),
-                jnp.asarray(lp.branches),
-                jnp.asarray(lp.ts),
-                jnp.asarray(lp.t_prev),
-                jnp.int32(lp.n_steps),
-                jnp.asarray(lp.thr),
-                mask, x_init, noise0,
-            )
-            self._lane_req[lane] = req
-            self._lane_step[lane] = 0
-            self._lane_admit_s[lane] = now_s
-            self._stall[lane] = 0
+            self._admit_lane(lane, req, now_s, shard)
 
     def _probe_cache(
         self, active: list[int], planned: np.ndarray
@@ -884,7 +895,7 @@ class ShardedDiffusionEngine(DiffusionEngine):
                 hits[lane] = hit
         return hits
 
-    def step(self, now_s: float = 0.0, clock: Callable[[], float] | None = None) -> list[CompletedRequest]:
+    def _tick(self, now_s: float, clock: Callable[[], float] | None) -> list[CompletedRequest]:
         """Backfill, run one sharded micro-step, retire finished lanes.
 
         Mirrors :meth:`DiffusionEngine.step` with the branch vote taken
@@ -897,103 +908,75 @@ class ShardedDiffusionEngine(DiffusionEngine):
         active = self._active_lanes()
         if not active:
             return []
-        t_step0 = time.perf_counter()
-
-        planned = np.array(
-            [self._lane_req[i]._lane_plan.branches[self._lane_step[i]] for i in active],
-            np.int64,
-        )
-        hit_slots = self._probe_cache(active, planned)
-        planned_of = {int(lane): int(planned[k]) for k, lane in enumerate(active)}
-        effective = planned.copy()
-        for k, lane in enumerate(active):
-            if lane in hit_slots:
-                effective[k] = SM.SKETCH if planned[k] == SM.FULL else SM.REFINE
 
         n = self.config.n_lanes
-        active_arr = np.asarray(active)
-        shard_ids = active_arr // self.lanes_per_shard
-        b_arr = np.full((self.n_shards,), SM.REFINE, np.int32)  # idle shards: cheapest
-        sel = np.zeros((n,), bool)
-        votes: list[tuple[int, int, np.ndarray]] = []  # (shard, b, advanced lanes)
-        for s in range(self.n_shards):
-            m = shard_ids == s
-            if not m.any():
-                continue
-            lanes_s = active_arr[m]
-            b = self.scheduler.pick_branch(effective[m], self._stall[lanes_s])
-            b_arr[s] = b
-            adv = lanes_s[effective[m] == b]
-            sel[adv] = True
-            votes.append((s, b, adv))
+        with self.metrics.span("engine.plan"):
+            planned = np.array(
+                [self._lane_req[i]._lane_plan.branches[self._lane_step[i]] for i in active],
+                np.int64,
+            )
+            hit_slots = self._probe_cache(active, planned)
+            planned_of = {int(lane): int(planned[k]) for k, lane in enumerate(active)}
+            effective = planned.copy()
+            for k, lane in enumerate(active):
+                if lane in hit_slots:
+                    effective[k] = SM.SKETCH if planned[k] == SM.FULL else SM.REFINE
 
-        n_full = sum(len(adv) for _, b, adv in votes if b == SM.FULL)
-        n_sketch = sum(len(adv) for _, b, adv in votes if b == SM.SKETCH)
-        n_refine = sum(len(adv) for _, b, adv in votes if b == SM.REFINE)
-        n_demoted = n_demoted_rf = 0
-        if self.cache is not None:
-            feat_src = np.full((n,), -1, np.int32)
-            feat_dist = np.full((n,), np.inf, np.float32)
-            for s, b, adv in votes:
-                if b not in (SM.SKETCH, SM.REFINE):
+            active_arr = np.asarray(active)
+            shard_ids = active_arr // self.lanes_per_shard
+            b_arr = np.full((self.n_shards,), SM.REFINE, np.int32)  # idle shards: cheapest
+            sel = np.zeros((n,), bool)
+            votes: list[tuple[int, int, np.ndarray]] = []  # (shard, b, advanced lanes)
+            for s in range(self.n_shards):
+                m = shard_ids == s
+                if not m.any():
                     continue
-                for lane in adv:
-                    hit = hit_slots.get(int(lane))
-                    if hit is None:
-                        req = self._lane_req[lane]
-                        if self._probe_eligible(req, int(lane), planned_of[int(lane)]):
-                            self.cache.note_miss(s)  # probed partial, no warm slot
+                lanes_s = active_arr[m]
+                b = self.scheduler.pick_branch(effective[m], self._stall[lanes_s])
+                b_arr[s] = b
+                adv = lanes_s[effective[m] == b]
+                sel[adv] = True
+                votes.append((s, b, adv))
+
+            n_full = sum(len(adv) for _, b, adv in votes if b == SM.FULL)
+            n_sketch = sum(len(adv) for _, b, adv in votes if b == SM.SKETCH)
+            n_refine = sum(len(adv) for _, b, adv in votes if b == SM.REFINE)
+            n_demoted = n_demoted_rf = 0
+            if self.cache is not None:
+                feat_src = np.full((n,), -1, np.int32)
+                feat_dist = np.full((n,), np.inf, np.float32)
+                for s, b, adv in votes:
+                    if b not in (SM.SKETCH, SM.REFINE):
                         continue
-                    slot, dist = hit
-                    feat_src[lane] = slot
-                    feat_dist[lane] = dist
-                    self.cache.note_hit(s, slot)
-                    if planned_of[int(lane)] == SM.FULL:
-                        n_demoted += 1
-                    else:
-                        n_demoted_rf += 1
-            self._state = self._micro(
-                self._state, self._params, jnp.asarray(b_arr), jnp.asarray(sel),
-                jnp.asarray(feat_src), jnp.asarray(feat_dist), self.cache.state,
-            )
-            # fresh captures -> shard-local warm slots, one sharded scatter:
-            # per-shard segments of the padded [n_lanes] index arrays carry
-            # local lane/slot indices (see ShardedFeatureCache.insert_many)
-            ins_lanes = np.zeros((n,), np.int32)
-            ins_slots = np.full((n,), self.cache.slots_per_shard, np.int32)
-            any_insert = False
-            for s, b, adv in votes:
-                if b != SM.FULL:
-                    continue
-                base = s * self.lanes_per_shard
-                pos = base
-                taken: set[int] = set()
-                for lane in adv:
-                    req = self._lane_req[lane]
-                    t = int(req._lane_plan.ts[self._lane_step[lane]])
-                    if req.allow_cache and self._lane_step[lane] >= self.config.cache_min_step:
-                        self.cache.note_miss(s)  # probed FULL executed as FULL
-                    if self.config.cache_mode == "intra" and not req.allow_cache:
-                        continue
-                    slot = self.cache.reserve(
-                        s, t, req._sig, req.rid, exclude=taken,
-                        offset=req.sched_offset,
-                    )
-                    if slot is None:  # shard ring smaller than the FULL batch
-                        continue
-                    taken.add(slot)
-                    ins_lanes[pos] = int(lane) - base  # shard-local lane index
-                    ins_slots[pos] = slot
-                    pos += 1
-                    any_insert = True
-            if any_insert:
-                self.cache.insert_many(
-                    self._state.f_sk, self._state.f_rf, ins_lanes, ins_slots
+                    for lane in adv:
+                        hit = hit_slots.get(int(lane))
+                        if hit is None:
+                            req = self._lane_req[lane]
+                            if self._probe_eligible(req, int(lane), planned_of[int(lane)]):
+                                self.cache.note_miss(s)  # probed partial, no warm slot
+                            continue
+                        slot, dist = hit
+                        feat_src[lane] = slot
+                        feat_dist[lane] = dist
+                        self.cache.note_hit(s, slot)
+                        if planned_of[int(lane)] == SM.FULL:
+                            n_demoted += 1
+                        else:
+                            n_demoted_rf += 1
+
+        cls = ",".join(CLASSES[b] for b in b_arr)
+        with self.metrics.span("engine.dispatch", cls=cls, lanes=int(sel.sum())):
+            self.metrics.mark_dispatch()
+            if self.cache is None:
+                self._state = self._micro(
+                    self._state, self._params, jnp.asarray(b_arr), jnp.asarray(sel)
                 )
-        else:
-            self._state = self._micro(
-                self._state, self._params, jnp.asarray(b_arr), jnp.asarray(sel)
-            )
+            else:
+                self._state = self._micro(
+                    self._state, self._params, jnp.asarray(b_arr), jnp.asarray(sel),
+                    jnp.asarray(feat_src), jnp.asarray(feat_dist), self.cache.state,
+                )
+                self._insert_shard_captures(votes)
 
         self._lane_step[sel] += 1
         self._stall[active] += 1
@@ -1006,31 +989,42 @@ class ShardedDiffusionEngine(DiffusionEngine):
             shard_active=shard_active,
         )
 
-        done: list[CompletedRequest] = []
-        for lane in active:
-            req = self._lane_req[lane]
-            if self._lane_step[lane] < req.timesteps:
+        return self._retire(active, now_s, clock)
+
+    def _insert_shard_captures(self, votes: list[tuple[int, int, np.ndarray]]) -> None:
+        """Fresh captures -> shard-local warm slots, one sharded scatter:
+        per-shard segments of the padded [n_lanes] index arrays carry local
+        lane/slot indices (see ShardedFeatureCache.insert_many)."""
+        n = self.config.n_lanes
+        ins_lanes = np.zeros((n,), np.int32)
+        ins_slots = np.full((n,), self.cache.slots_per_shard, np.int32)
+        any_insert = False
+        for s, b, adv in votes:
+            if b != SM.FULL:
                 continue
-            latent = self._state.x[lane]
-            image = None
-            if self._decoder is not None:
-                image = np.asarray(self._decoder(latent[None])[0])
-            latent = np.asarray(latent)  # syncs the queued micro-steps
-            done.append(
-                CompletedRequest(
-                    rid=req.rid,
-                    latent=latent,
-                    image=image,
-                    submitted_s=req.arrival_s,
-                    admitted_s=self._lane_admit_s[lane],
-                    completed_s=clock() if clock is not None else now_s,
+            base = s * self.lanes_per_shard
+            pos = base
+            taken: set[int] = set()
+            for lane in adv:
+                req = self._lane_req[lane]
+                t = int(req._lane_plan.ts[self._lane_step[lane]])
+                if req.allow_cache and self._lane_step[lane] >= self.config.cache_min_step:
+                    self.cache.note_miss(s)  # probed FULL executed as FULL
+                if self.config.cache_mode == "intra" and not req.allow_cache:
+                    continue
+                slot = self.cache.reserve(
+                    s, t, req._sig, req.rid, exclude=taken,
+                    offset=req.sched_offset,
                 )
-            )
-            self._release_lane(lane)
-            self._lane_req[lane] = None
-            self.metrics.record_completion(done[-1].latency_s, done[-1].queue_wait_s)
-        self.metrics.record_step_time(self.config.backend, time.perf_counter() - t_step0)
-        return done
+                if slot is None:  # shard ring smaller than the FULL batch
+                    continue
+                taken.add(slot)
+                ins_lanes[pos] = int(lane) - base  # shard-local lane index
+                ins_slots[pos] = slot
+                pos += 1
+                any_insert = True
+        if any_insert:
+            self.cache.insert_many(self._state.f_sk, self._state.f_rf, ins_lanes, ins_slots)
 
 
 def make_serving_engine(

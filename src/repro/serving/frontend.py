@@ -47,9 +47,12 @@ Endpoints (HTTP/1.1, ``Connection: close``):
     per-branch-class executed-step counts (``full_steps`` /
     ``sketch_steps`` / ``refine_steps``), cache demotions + hit rate, and
     the per-quality-tier request mix (``quality_mix``), the active kernel
-    backend (``kernels``) and per-backend micro-step timing
-    (``step_time_by_backend``), so mixed-quality streams are observable
-    without the bench harness.
+    backend (``kernels``), micro-steps per branch class
+    (``ticks_by_class``), held lane slots (``lane_slots_active``), the
+    host spans of the engine and driver (``spans``: ``{name: {n, s}}``)
+    and the host gap after each retirement sync (``drain_gap_s``,
+    ``drains``), so mixed-quality streams are observable without the
+    bench harness.
 ``GET /cache/keys?since=N``
     Incremental cache-key gossip: warm-slot key rows (bucket, signature,
     schedule offset, generation stamp — never features) written after

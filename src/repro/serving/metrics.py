@@ -1,29 +1,63 @@
-"""Serving metrics: latency percentiles, throughput, lane occupancy.
+"""Serving metrics: the engine's and driver's counters and spans.
 
-The engine records one sample per micro-step (occupancy = fraction of lanes
-holding a request, advance efficiency = fraction of *active* lanes the step
-actually moved) and one sample per completed request (queue + service
-latency).  ``summary()`` collapses everything into the flat dict printed by
-``launch/serve.py`` and consumed by ``benchmarks/bench_serving.py``.
+The engine records one sample per micro-step (``record_step``: occupancy =
+fraction of lanes holding a request, advance efficiency = fraction of
+*active* lanes the step actually moved) and one sample per completed
+request (queue + service latency).  Per-tick quantities are kept as running
+sums, so ``summary()`` costs the same at any uptime and two summaries taken
+apart give a window's deltas.  ``summary()`` collapses everything into the
+flat dict printed by ``launch/serve.py``, served at ``GET /stats`` and
+consumed by ``benchmarks/bench_serving.py``.
+
+``span(name, **ids)`` is the program's one tracing hook: it opens a
+``jax.profiler.TraceAnnotation`` (so the span sits on the profiler's host
+timeline, ids as its metadata) and adds the interval to ``spans[name]``.
+It is always on and never touches a device value, so it adds no sync.
+JAX is imported only when a span opens: the router's process imports this
+module and stays jax-free.
+
+``drain_gap_s`` / ``drains`` measure the host time between a tick's last
+retirement sync (``mark_sync``, after the latent read-back returns: the
+device has run out of queued work) and the next micro-step dispatch
+(``mark_dispatch``).  A sync followed by the engine running dry
+(``mark_idle``) is not counted: that gap is no work, not host time.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Sequence
+import time
+from typing import Iterator, Sequence
 
 import numpy as np
+
+#: branch class names, costliest first, indexed like the sampler's
+#: ``FULL, SKETCH, REFINE = 0, 1, 2``
+CLASSES = ("full", "sketch", "refine")
 
 
 @dataclasses.dataclass
 class ServingMetrics:
     latencies_s: list[float] = dataclasses.field(default_factory=list)
     queue_waits_s: list[float] = dataclasses.field(default_factory=list)
-    occupancy: list[float] = dataclasses.field(default_factory=list)
-    advance_eff: list[float] = dataclasses.field(default_factory=list)
-    #: per-micro-step active-lane count per shard (sharded engine only)
-    shard_active: list[list[int]] = dataclasses.field(default_factory=list)
     micro_steps: int = 0
     lane_steps_advanced: int = 0
+    #: active lanes summed over micro-steps (held slots, advanced or not)
+    lane_slots_active: int = 0
+    #: micro-steps per branch class; a sharded tick counts under the
+    #: costliest class any shard advanced (the one that sets its length)
+    ticks_by_class: dict[str, int] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(CLASSES, 0)
+    )
+    #: running sums behind ``mean_occupancy`` / ``mean_advance_eff``
+    occupancy_sum: float = 0.0
+    advance_eff_sum: float = 0.0
+    #: micro-steps with at least one active lane (``advance_eff`` samples)
+    advance_eff_n: int = 0
+    #: per-shard active-lane counts summed over micro-steps, and the
+    #: number of such samples (sharded engine only)
+    shard_active_sum: list[int] = dataclasses.field(default_factory=list)
+    shard_ticks: int = 0
     #: FULL lane-steps actually executed (each one a full U-Net pass)
     full_steps: int = 0
     #: SKETCH / REFINE lane-steps actually executed (partial U-Net passes,
@@ -45,10 +79,14 @@ class ServingMetrics:
     gossip_routed: int = 0
     #: submitted requests per resolved quality tier ("full"/"pas" = legacy)
     quality_mix: dict[str, int] = dataclasses.field(default_factory=dict)
-    #: host wall seconds spent in ``engine.step`` per kernel backend
-    #: (dispatch + any retirement sync) — {backend: [count, total_s]}
-    step_time_by_backend: dict[str, list] = dataclasses.field(default_factory=dict)
+    #: host spans by name: [count, total seconds] (see ``span``)
+    spans: dict[str, list] = dataclasses.field(default_factory=dict)
+    #: host seconds from a retirement sync to the next dispatch, and how
+    #: many such gaps were counted
+    drain_gap_s: float = 0.0
+    drains: int = 0
     wall_s: float = 0.0
+    _t_sync: float | None = dataclasses.field(default=None, repr=False)
 
     def record_step(
         self,
@@ -64,6 +102,10 @@ class ServingMetrics:
     ) -> None:
         self.micro_steps += 1
         self.lane_steps_advanced += n_advanced
+        self.lane_slots_active += n_active
+        cls = next((c for c, n in zip(CLASSES, (n_full, n_sketch, n_refine)) if n), None)
+        if cls is not None:
+            self.ticks_by_class[cls] += 1
         self.full_steps += n_full
         self.sketch_steps += n_sketch
         self.refine_steps += n_refine
@@ -71,21 +113,51 @@ class ServingMetrics:
         self.demoted_refine_steps += n_demoted_refine
         # every executed demotion was served by a device-resident slot
         self.hbm_hits += n_demoted + n_demoted_refine
-        self.occupancy.append(n_active / max(n_lanes, 1))
+        self.occupancy_sum += n_active / max(n_lanes, 1)
         if n_active:
-            self.advance_eff.append(n_advanced / n_active)
+            self.advance_eff_sum += n_advanced / n_active
+            self.advance_eff_n += 1
         if shard_active is not None:
-            self.shard_active.append(list(shard_active))
+            base = self.shard_active_sum or [0] * len(shard_active)
+            self.shard_active_sum = [s + a for s, a in zip(base, shard_active)]
+            self.shard_ticks += 1
 
     def record_submission(self, tier: str) -> None:
         """Count one submitted request under its resolved quality tier."""
         self.quality_mix[tier] = self.quality_mix.get(tier, 0) + 1
 
-    def record_step_time(self, backend: str, seconds: float) -> None:
-        """Accumulate one micro-step's host wall time under its backend."""
-        acc = self.step_time_by_backend.setdefault(backend, [0, 0.0])
-        acc[0] += 1
-        acc[1] += seconds
+    @contextlib.contextmanager
+    def span(self, name: str, **ids) -> Iterator[None]:
+        """Time the enclosed host work as ``name``: a profiler host span
+        carrying ``ids`` as metadata, and one more interval in
+        ``spans[name]``."""
+        from jax.profiler import TraceAnnotation
+
+        t0 = time.perf_counter()
+        try:
+            with TraceAnnotation(name, **ids):
+                yield
+        finally:
+            acc = self.spans.setdefault(name, [0, 0.0])
+            acc[0] += 1
+            acc[1] += time.perf_counter() - t0
+
+    def mark_sync(self) -> None:
+        """A retirement read-back returned: the device queue is empty."""
+        self._t_sync = time.perf_counter()
+
+    def mark_dispatch(self) -> None:
+        """A micro-step is being dispatched: close the drain gap, if a sync
+        happened since the last dispatch."""
+        if self._t_sync is not None:
+            self.drain_gap_s += time.perf_counter() - self._t_sync
+            self.drains += 1
+            self._t_sync = None
+
+    def mark_idle(self) -> None:
+        """The engine holds no work: the wait for the next request is not a
+        drain gap."""
+        self._t_sync = None
 
     def record_completion(self, latency_s: float, queue_wait_s: float) -> None:
         self.latencies_s.append(latency_s)
@@ -105,11 +177,13 @@ class ServingMetrics:
             else 0.0,
             "micro_steps": self.micro_steps,
             "lane_steps_advanced": self.lane_steps_advanced,
-            "mean_occupancy": round(float(np.mean(self.occupancy)), 3)
-            if self.occupancy
+            "lane_slots_active": self.lane_slots_active,
+            "ticks_by_class": dict(self.ticks_by_class),
+            "mean_occupancy": round(self.occupancy_sum / self.micro_steps, 3)
+            if self.micro_steps
             else 0.0,
-            "mean_advance_eff": round(float(np.mean(self.advance_eff)), 3)
-            if self.advance_eff
+            "mean_advance_eff": round(self.advance_eff_sum / self.advance_eff_n, 3)
+            if self.advance_eff_n
             else 0.0,
             "full_steps": self.full_steps,
             "sketch_steps": self.sketch_steps,
@@ -126,10 +200,11 @@ class ServingMetrics:
             "spill_promotions": self.spill_promotions,
             "gossip_routed": self.gossip_routed,
             "quality_mix": dict(sorted(self.quality_mix.items())),
-            "step_time_by_backend": {
-                k: {"steps": c, "mean_s": round(t / max(c, 1), 6)}
-                for k, (c, t) in sorted(self.step_time_by_backend.items())
+            "spans": {
+                k: {"n": c, "s": round(t, 6)} for k, (c, t) in sorted(self.spans.items())
             },
+            "drain_gap_s": round(self.drain_gap_s, 6),
+            "drains": self.drains,
             **self._shard_summary(),
         }
 
@@ -140,13 +215,11 @@ class ServingMetrics:
         active-lane counts: 1.0 = perfectly balanced admission, 0.0 = at
         least one shard sat idle the whole run.
         """
-        if not self.shard_active:
+        if not self.shard_ticks:
             return {}
-        per_shard = np.asarray(self.shard_active, np.float64).mean(axis=0)
-        peak = float(per_shard.max())
+        per_shard = [a / self.shard_ticks for a in self.shard_active_sum]
+        peak = max(per_shard)
         return {
-            "shard_mean_active": [round(float(v), 3) for v in per_shard],
-            "shard_occupancy_balance": round(
-                float(per_shard.min()) / peak, 3
-            ) if peak > 0 else 0.0,
+            "shard_mean_active": [round(v, 3) for v in per_shard],
+            "shard_occupancy_balance": round(min(per_shard) / peak, 3) if peak > 0 else 0.0,
         }

@@ -147,11 +147,21 @@ def test_engine_matches_straight_line_sampler(engine):
         )
 
 
-def test_engine_backfills_and_retires(engine):
+def test_engine_backfills_and_retires(engine, monkeypatch):
     """More requests than lanes: every lane retirement must immediately
     admit the next queued request, keeping occupancy at 1 until the queue
     drains."""
+    from repro.serving.metrics import ServingMetrics
+
     eng, _ = engine
+    occ = []  # per-micro-step occupancy, as the engine reports each tick
+    record = ServingMetrics.record_step
+
+    def spy(self, n_lanes, n_active, *a, **k):
+        occ.append(n_active / n_lanes)
+        return record(self, n_lanes, n_active, *a, **k)
+
+    monkeypatch.setattr(ServingMetrics, "record_step", spy)
     reqs = [_request(i, 3, None, seed=50 + i) for i in range(5)]
     done, summary = eng.run(reqs)
     assert sorted(d.rid for d in done) == list(range(5))
@@ -160,8 +170,10 @@ def test_engine_backfills_and_retires(engine):
     # busy for 6 micro-steps, then the odd request drains alone for 3.
     assert summary["micro_steps"] == 9
     assert summary["mean_advance_eff"] == 1.0
-    occ = eng.metrics.occupancy
+    assert len(occ) == 9
     assert all(o == 1.0 for o in occ[:6]) and all(o == 0.5 for o in occ[6:])
+    assert summary["lane_slots_active"] == 15
+    assert summary["mean_occupancy"] == round(7.5 / 9, 3)
     # FIFO admission: first two completions are the first two submissions
     assert {done[0].rid, done[1].rid} == {0, 1}
 

@@ -198,7 +198,7 @@ def _run_pallas_engine(params, reqs, batch: int, max_steps: int):
     )
     done, summary = DiffusionEngine(TOY, dcfg, params, None, cfg).run(reqs)
     assert summary["kernels"] == "pallas"
-    assert summary["step_time_by_backend"]["pallas"]["steps"] > 0
+    assert summary["spans"]["engine.tick"]["n"] > 0
     return {d.rid: d.latent for d in done}
 
 

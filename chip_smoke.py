@@ -180,9 +180,10 @@ def micro_step_args(engine) -> tuple:
     import jax.numpy as jnp
     import numpy as np
 
-    n = engine.config.n_lanes
+    n, k = engine.config.n_lanes, engine._k
     return (
-        engine._state, engine._params, jnp.int32(0), jnp.asarray(np.zeros((n,), bool)),
+        engine._state, engine._params, jnp.int32(0), jnp.arange(k, dtype=jnp.int32),
+        jnp.asarray(np.zeros((k,), bool)),
         jnp.asarray(np.full((n,), -1, np.int32)), jnp.asarray(np.full((n,), np.inf, np.float32)),
         engine.cache.state,
     )

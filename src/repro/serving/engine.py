@@ -2,12 +2,15 @@
 
 The engine advances a fixed set of *lanes* through the PAS denoise loop one
 micro-step at a time.  Lanes hold requests at heterogeneous denoise steps;
-each micro-step executes one branch class (FULL / SKETCH / REFINE) chosen by
-the packing policy as a single batched U-Net invocation, so a micro-step
-costs what one step of an equally wide static batch costs.  Lanes retire
-through the VAE decoder the moment their own schedule finishes and are
-immediately backfilled from the admission queue — no lane ever waits for a
-batch-mate (the lockstep waste ``serve_static`` below exists to measure).
+each micro-step (tick) executes one branch class (FULL / SKETCH / REFINE)
+chosen by the packing policy, on the lanes that class advances only: they
+are compacted into dispatches of ``ceil(n_lanes / 2)`` lanes, so a tick
+that advances few lanes runs the U-Net on half the rows of the whole
+batch, and one that advances most of them about the work of one
+whole-batch step.  Lanes retire through the VAE decoder the moment their
+own schedule finishes and are immediately backfilled from the admission
+queue — no lane ever waits for a batch-mate (the lockstep waste
+``serve_static`` below exists to measure).
 
 Requests may differ in step count and in phase boundaries (``t_sketch``,
 ``t_complete``, ``t_sparse``) — the branch *plan* is per-lane.  The feature
@@ -272,6 +275,8 @@ class DiffusionEngine:
             ucfg, self.dcfg, self.e_sk, self.e_rf,
             cached=self.cache is not None, backend=config.backend,
         )
+        #: lanes per compact micro-step dispatch (``LN.compact_capacity``)
+        self._k = LN.compact_capacity(config.n_lanes)
         self._admit = jax.jit(LN.admit, donate_argnums=(0,))
 
     # -- submission ---------------------------------------------------------
@@ -588,19 +593,19 @@ class DiffusionEngine:
                         else:
                             n_demoted_rf += 1
 
+        batches = LN.compact_batches(advanced, self.config.n_lanes, self._k)
         with self.metrics.span("engine.dispatch", cls=CLASSES[b_star], lanes=len(advanced)):
             self.metrics.mark_dispatch()
-            if self.cache is None:
+            extra = () if self.cache is None else (
+                jnp.asarray(feat_src), jnp.asarray(feat_dist), self.cache.state
+            )
+            for idx, valid in batches:
                 self._state = self._micro(
-                    self._state, self._params, jnp.int32(b_star), jnp.asarray(sel)
+                    self._state, self._params, jnp.int32(b_star),
+                    jnp.asarray(idx), jnp.asarray(valid), *extra,
                 )
-            else:
-                self._state = self._micro(
-                    self._state, self._params, jnp.int32(b_star), jnp.asarray(sel),
-                    jnp.asarray(feat_src), jnp.asarray(feat_dist), self.cache.state,
-                )
-                if b_star == SM.FULL:
-                    self._insert_captures(advanced)
+            if self.cache is not None and b_star == SM.FULL:
+                self._insert_captures(advanced)
 
         self._lane_step[sel] += 1
         self._stall[active] += 1
@@ -612,6 +617,7 @@ class DiffusionEngine:
             n_sketch=n_adv if b_star == SM.SKETCH else 0,
             n_refine=n_adv if b_star == SM.REFINE else 0,
             n_demoted=n_demoted, n_demoted_refine=n_demoted_rf,
+            dispatches=len(batches), unet_rows=2 * self._k * len(batches),
         )
 
         return self._retire(active, now_s, clock)
@@ -986,7 +992,7 @@ class ShardedDiffusionEngine(DiffusionEngine):
             n, len(active), int(sel.sum()),
             n_full=n_full, n_sketch=n_sketch, n_refine=n_refine,
             n_demoted=n_demoted, n_demoted_refine=n_demoted_rf,
-            shard_active=shard_active,
+            shard_active=shard_active, dispatches=1, unet_rows=2 * n,
         )
 
         return self._retire(active, now_s, clock)

@@ -18,7 +18,7 @@ The golden file pins three executions:
 
 Each execution is asserted *bit-exactly* against its own golden family.
 The two families are additionally cross-checked within a small tolerance:
-they run different XLA programs (scan + scalar timestep vs batched masked
+they run different XLA programs (scan + scalar timestep vs gathered lane
 micro-steps), which fuse differently, so cross-family bit equality is not
 achievable — empirically they agree to ~1e-4 on the toy config.  Any
 refactor of the sampler, lanes, engine, or cache that moves a single bit

@@ -6,8 +6,9 @@ multistep ring, sketch/refine feature caches, branch vector — inside one
 :class:`LaneState` pytree so a serving engine can:
 
 * advance lanes sitting at *heterogeneous* denoise steps in one jitted
-  micro-step (one ``lax.switch``-selected U-Net invocation over the whole
-  lane batch, driven by each lane's precomputed branch plan),
+  micro-step (one ``lax.switch``-selected U-Net invocation over a compact
+  gather of the lanes the step advances, driven by each lane's
+  precomputed branch plan),
 * admit a new request into a retired lane by scatter (``admit``), and
 * read a finished lane's latent by gather (``gather_latent``).
 
@@ -21,8 +22,8 @@ Layout notes
   cache slot without any transpose.
 * Per-lane plans are padded to ``max_steps``; ``step[i] < n_steps[i]``
   defines liveness, so the padded tail never executes.  An empty lane has
-  ``n_steps == 0`` and all-zero tensors (zeros keep the masked-out batched
-  compute NaN-free).
+  ``n_steps == 0`` and all-zero tensors (zeros keep the compute on a
+  padding lane NaN-free).
 """
 from __future__ import annotations
 
@@ -207,6 +208,70 @@ def gather_latent(state: LaneState, lane: int) -> jax.Array:
     return state.x[lane]
 
 
+#: LaneState leaves in the CFG-doubled ``[2N, ...]`` layout
+_CFG_ROWS = frozenset({"f_sk", "f_rf", "ctx2"})
+#: the leaves a micro-step writes; every other leaf only changes at admission
+_STEPPED = ("x", "ets", "n_ets", "f_sk", "f_rf", "step")
+
+
+def compact_capacity(n_lanes: int) -> int:
+    """Lanes per compact micro-step dispatch: ``ceil(n_lanes / 2)``.
+
+    A tick that advances at most this many lanes runs the U-Net on half
+    the rows of the whole lane batch; one that advances more takes two
+    dispatches, about the work of one whole-batch pass.  One lane gives
+    ``K == N``, the whole batch.
+    """
+    return -(-n_lanes // 2)
+
+
+def compact_batches(
+    advanced: np.ndarray, n_lanes: int, k: int
+) -> list[tuple[np.ndarray, np.ndarray]]:
+    """Split a tick's advancing lanes into ``(idx [k], valid [k])`` dispatches.
+
+    ``ceil(len(advanced) / k)`` dispatches, each of ``k`` distinct lanes:
+    up to ``k`` advancing lanes (``valid``), padded with lanes the tick
+    does not advance, lowest-numbered first.  A padding lane is gathered
+    and written back unchanged, so it must not be one the same dispatch
+    advances (duplicate scatter indices have no defined order).  With an
+    even lane count the non-advancing lanes always suffice; with an odd
+    one the last dispatch may also pad with a lane an earlier dispatch of
+    the tick advanced, which it then writes back as that dispatch left it.
+    """
+    adv = {int(lane) for lane in advanced}
+    out = []
+    for lo in range(0, len(advanced), k):
+        chunk = [int(lane) for lane in advanced[lo : lo + k]]
+        others = sorted(set(range(n_lanes)) - set(chunk), key=lambda lane: (lane in adv, lane))
+        valid = np.zeros((k,), bool)
+        valid[: len(chunk)] = True
+        out.append((np.asarray(chunk + others[: k - len(chunk)], np.int32), valid))
+    return out
+
+
+def _gather_lanes(state: LaneState, idx: jax.Array) -> LaneState:
+    """The ``K`` lanes ``idx`` as a K-lane :class:`LaneState` (their CFG
+    rows ``idx`` and ``N + idx``)."""
+    rows = jnp.concatenate([idx, state.n_lanes + idx])
+    return LaneState(*(
+        leaf[rows] if name in _CFG_ROWS else leaf[idx]
+        for name, leaf in zip(LaneState._fields, state)
+    ))
+
+
+def _scatter_lanes(state: LaneState, idx: jax.Array, sub: LaneState) -> LaneState:
+    """Write a gathered K-lane state's stepped leaves back to lanes ``idx``
+    (``idx`` distinct)."""
+    rows = jnp.concatenate([idx, state.n_lanes + idx])
+    return state._replace(**{
+        name: getattr(state, name).at[rows if name in _CFG_ROWS else idx].set(
+            getattr(sub, name)
+        )
+        for name in _STEPPED
+    })
+
+
 def make_micro_step(
     ucfg: UNetConfig,
     dcfg: DiffusionConfig,
@@ -218,39 +283,43 @@ def make_micro_step(
 ):
     """Build the jitted continuous-batching micro-step.
 
-    The returned function advances, by exactly one denoise step, every
-    active lane the host-chosen advance mask ``sel`` selects (the lanes
+    The returned function advances, by exactly one denoise step, the lanes
+    of one compact dispatch: ``idx`` [K] distinct lane indices, of which
+    those with ``valid`` [K] set advance (the host's advance choice: lanes
     whose *effective* branch class equals the scalar ``b_star`` chosen by
-    the packing policy) — one batched ``lax.switch``-selected U-Net
-    invocation for the whole lane batch, so a micro-step costs the same as
-    one step of an equally wide static batch.  Lanes in other branch
-    classes (and empty lanes) are carried through untouched via masking.
-    ``sel`` comes from the host because the cache-aware engine may *demote*
-    a lane's planned FULL step to SKETCH, which the device-side plan alone
-    cannot see.
+    the packing policy).  It gathers those K lanes — 2K CFG rows — runs
+    one ``lax.switch``-selected U-Net invocation over them, and scatters
+    the new state back where ``valid``; padding lanes (``valid`` false)
+    and every lane outside ``idx`` are carried through untouched.  The
+    engine splits a tick's advancing lanes into ``ceil(n / K)`` such
+    dispatches (:func:`compact_batches`), so the U-Net runs on the rows a
+    tick advances rather than on the whole lane batch.  The advance
+    choice comes from the host because the cache-aware engine may
+    *demote* a lane's planned FULL step to SKETCH, which the device-side
+    plan alone cannot see.
 
-    ``cached=False`` — signature ``(state, params, b_star, sel)``: partial branches
-    consume the lane's own captured features (the PR 1 behaviour).
+    ``cached=False`` — signature ``(state, params, b_star, idx, valid)``:
+    partial branches consume the lane's own captured features.
 
-    ``cached=True`` — signature ``(state, params, b_star, sel, feat_src,
-    feat_dist, cache)``: ``feat_src`` is a per-lane int32 slot index into the
-    device-resident feature cache (-1 = own features) and ``feat_dist`` the
-    probed slot's prompt-signature distance; the slot is consumed only
-    where ``feat_dist`` is *strictly* below the lane's per-step threshold
-    leaf (``state.thr`` — the quality policy's per-request resolution, so
-    the quality comparison happens on device, not against a python
-    scalar).  The partial branches consume the selected entry; on a SKETCH
-    step the selection also becomes the lane's sketch/refine cache (a
-    demoted FULL skipped its own refresh, so the slot is its feature
-    source of record), while a REFINE step consumes it for that step only
-    and leaves the lane's own captures in place.  With ``feat_src`` all -1
-    (or a threshold-0 lane, for which the strict inequality never passes)
-    the selection is an exact passthrough — the cache-enabled micro-step
-    with no hits is bit-identical to ``cached=False`` (the golden-latent
-    harness pins this).
+    ``cached=True`` — signature ``(state, params, b_star, idx, valid,
+    feat_src, feat_dist, cache)``: ``feat_src`` is a per-lane [N] int32
+    slot index into the device-resident feature cache (-1 = own features)
+    and ``feat_dist`` [N] the probed slot's prompt-signature distance; the
+    slot is consumed only where ``feat_dist`` is *strictly* below the
+    lane's per-step threshold leaf (``state.thr`` — the quality policy's
+    per-request resolution, so the quality comparison happens on device,
+    not against a python scalar).  The partial branches consume the
+    selected entry; on a SKETCH step the selection also becomes the lane's
+    sketch/refine cache (a demoted FULL skipped its own refresh, so the
+    slot is its feature source of record), while a REFINE step consumes it
+    for that step only and leaves the lane's own captures in place.  With
+    ``feat_src`` all -1 (or a threshold-0 lane, for which the strict
+    inequality never passes) the selection is an exact passthrough — the
+    cache-enabled micro-step with no hits is bit-identical to
+    ``cached=False`` (the golden-latent harness pins this).
 
     The step returns only the new state (no per-step host readback): the
-    advance mask is deterministic from the host-known plans + cache
+    advance choice is deterministic from the host-known plans + cache
     metadata, so the engine mirrors it host-side and the device stays on
     the async-dispatch fast path.  The input state is donated — callers
     must drop their reference.
@@ -269,12 +338,12 @@ def make_micro_step(
     use_pndm = dcfg.scheduler == "pndm"
 
     def _body(
-        state: LaneState,
+        state: LaneState,  # the K gathered lanes
         params: Params,
         b_star: jax.Array,
-        sel: jax.Array,  # [N] bool host-computed advance mask
-        entry_sk: jax.Array,  # [2N, ...] features the SKETCH branch consumes
-        entry_rf: jax.Array,  # [2N, ...] features the REFINE branch consumes
+        sel: jax.Array,  # [K] bool: which gathered lanes advance
+        entry_sk: jax.Array,  # [2K, ...] features the SKETCH branch consumes
+        entry_rf: jax.Array,  # [2K, ...] features the REFINE branch consumes
     ) -> LaneState:
         idx = jnp.minimum(state.step, state.branches.shape[1] - 1)
         take = lambda a: jnp.take_along_axis(a, idx[:, None], axis=1)[:, 0]
@@ -346,9 +415,13 @@ def make_micro_step(
     if not cached:
 
         def micro_step(
-            state: LaneState, params: Params, b_star: jax.Array, sel: jax.Array
+            state: LaneState, params: Params, b_star: jax.Array, idx: jax.Array,
+            valid: jax.Array,
         ) -> LaneState:
-            return _body(state, params, b_star, sel, state.f_sk, state.f_rf)
+            sub = _gather_lanes(state, idx)
+            return _scatter_lanes(
+                state, idx, _body(sub, params, b_star, valid, sub.f_sk, sub.f_rf)
+            )
 
         return jax.jit(micro_step, donate_argnums=(0,))
 
@@ -356,19 +429,24 @@ def make_micro_step(
         state: LaneState,
         params: Params,
         b_star: jax.Array,
-        sel: jax.Array,
+        idx: jax.Array,
+        valid: jax.Array,
         feat_src: jax.Array,  # [N] int32 cache slot per lane, -1 = own
         feat_dist: jax.Array,  # [N] f32 probed slot signature distance (inf = none)
         cache,  # CacheState pytree of [S, 2, ...] slots
     ) -> LaneState:
-        idx = jnp.minimum(state.step, state.thr.shape[1] - 1)
-        thr_t = jnp.take_along_axis(state.thr, idx[:, None], axis=1)[:, 0]
+        sub = _gather_lanes(state, idx)
+        at = jnp.minimum(sub.step, sub.thr.shape[1] - 1)
+        thr_t = jnp.take_along_axis(sub.thr, at[:, None], axis=1)[:, 0]
+        src = feat_src[idx]
         # strict inequality against the lane's own threshold leaf: a
         # threshold-0 lane can never consume a slot, whatever the host says
-        use = (feat_src >= 0) & (feat_dist < thr_t)
-        entry_sk = select_entry_features(state.f_sk, cache.f_sk, feat_src, use)
-        entry_rf = select_entry_features(state.f_rf, cache.f_rf, feat_src, use)
-        return _body(state, params, b_star, sel, entry_sk, entry_rf)
+        use = (src >= 0) & (feat_dist[idx] < thr_t)
+        entry_sk = select_entry_features(sub.f_sk, cache.f_sk, src, use)
+        entry_rf = select_entry_features(sub.f_rf, cache.f_rf, src, use)
+        return _scatter_lanes(
+            state, idx, _body(sub, params, b_star, valid, entry_sk, entry_rf)
+        )
 
     return jax.jit(micro_step_cached, donate_argnums=(0,))
 

@@ -49,6 +49,11 @@ class ServingMetrics:
     ticks_by_class: dict[str, int] = dataclasses.field(
         default_factory=lambda: dict.fromkeys(CLASSES, 0)
     )
+    #: micro-step dispatches, and the U-Net rows they computed (CFG rows,
+    #: padding and masked rows included): ``1 - 2 * lane_steps_advanced /
+    #: unet_rows`` is the share of computed rows thrown away
+    micro_dispatches: int = 0
+    unet_rows: int = 0
     #: running sums behind ``mean_occupancy`` / ``mean_advance_eff``
     occupancy_sum: float = 0.0
     advance_eff_sum: float = 0.0
@@ -99,8 +104,12 @@ class ServingMetrics:
         n_refine: int = 0,
         n_demoted_refine: int = 0,
         shard_active: Sequence[int] | None = None,
+        dispatches: int = 0,
+        unet_rows: int = 0,
     ) -> None:
         self.micro_steps += 1
+        self.micro_dispatches += dispatches
+        self.unet_rows += unet_rows
         self.lane_steps_advanced += n_advanced
         self.lane_slots_active += n_active
         cls = next((c for c, n in zip(CLASSES, (n_full, n_sketch, n_refine)) if n), None)
@@ -179,6 +188,8 @@ class ServingMetrics:
             "lane_steps_advanced": self.lane_steps_advanced,
             "lane_slots_active": self.lane_slots_active,
             "ticks_by_class": dict(self.ticks_by_class),
+            "micro_dispatches": self.micro_dispatches,
+            "unet_rows": self.unet_rows,
             "mean_occupancy": round(self.occupancy_sum / self.micro_steps, 3)
             if self.micro_steps
             else 0.0,

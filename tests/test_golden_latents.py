@@ -17,7 +17,7 @@ forces 512 host devices), which shifts XLA:CPU numerics at the ulp level.
 Same-process equivalences (threshold 0 vs cache off) and tolerance checks
 are flag-regime independent and run in-process.
 
-The two golden families run different XLA programs (scan vs batched masked
+The two golden families run different XLA programs (scan vs gathered lane
 micro-steps) and are only cross-checked within a small tolerance; see
 ``repro.serving.golden``.  Regenerate after intentional numerics changes
 with ``PYTHONPATH=src python tools/regen_golden_latents.py``.

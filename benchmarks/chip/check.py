@@ -2,14 +2,15 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the requests the window finished, drawn from the seed, goes through the
-float32 reference (``reference.py``): the one with the most steps, one of
-each quality tier the window served, then more drawn at random up to the
-traffic file's ``check_sample``.  Each served latent is first matched to
-the digest its ``done`` event streamed, so the latent compared is the one
-the client was told about.  The number compared is the largest relative
-L2 gap, ``|served - reference| / |reference|``, over the sample; its limit
-is ``limits/<cell>.json``, set from sound runs and from the reference run
-at float8 (the control), as ``PERF.md`` records.
+float32 reference (the ``Sampler`` of the configuration's
+``arch/<name>.py``): the one with the most steps, one of each quality tier
+the window served, then more drawn at random up to the traffic file's
+``check_sample``.  Each served latent is first matched to the digest its
+``done`` event streamed, so the latent compared is the one the client was
+told about.  The number compared is the largest relative L2 gap,
+``|served - reference| / |reference|``, over the sample; its limit is
+``limits/<cell>.json``, set from sound runs and from the reference run at
+float8 (the control), as ``PERF.md`` records.
 """
 from __future__ import annotations
 
@@ -19,7 +20,7 @@ import time
 
 import numpy as np
 
-from benchmarks.chip import reference, spec
+from benchmarks.chip import spec
 
 
 def digest(latent: np.ndarray) -> str:
@@ -63,8 +64,8 @@ def compare(cell: spec.Cell, rec, latents: dict, params, seed: int, limits: dict
     picked = sample(rec.window_reqs(), cell.traffic["check_sample"], seed)
     known = [r for r in picked if r.rid in latents]
     t = time.perf_counter()
-    sampler = reference.Sampler(cell.config, rec.dims, params_f32(params),
-                                batch=cell.traffic["check_sample"])
+    sampler = spec.arch(cell.config).Sampler(cell.config, rec.dims, params_f32(params),
+                                             batch=cell.traffic["check_sample"])
     wants = sampler.run_many([(r.prompt, r.seed, r.tier, r.steps) for r in known])
     log(f"reference ran {len(known)} requests in {time.perf_counter() - t:.3f} s")
     return judge(cell, picked, latents, wants, limits, log)

@@ -10,12 +10,12 @@ engine (the micro-step takes them as an argument, so nothing compiles
 again), one window of the cell's own traffic at the cell's own load and
 its drain, the sample that a benchmark run would compare drawn the same
 way, and the verdict of ``check.judge`` on it against the float32
-reference: the sound program's reading.  For the first
-``--control-seeds`` seeds the same requests also run through the
-reference at float8 (``quant="fp8"``), put in the program's place, and
-``check.judge`` gives the control's verdict and reading.  One JSON line
-per seed; ``PERF.md`` records the readings and the limit set between
-them.
+reference of the configuration's ``arch/<name>.py``: the sound program's
+reading.  For the first ``--control-seeds`` seeds the same requests also
+run through that reference at float8 (``quant="fp8"``), put in the
+program's place, and ``check.judge`` gives the control's verdict and
+reading.  One JSON line per seed; ``PERF.md`` records the readings and the
+limit set between them.
 """
 from __future__ import annotations
 
@@ -32,14 +32,15 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from benchmarks.chip import check, reference, run, spec, system  # noqa: E402
+from benchmarks.chip import check, run, spec, system  # noqa: E402
 
 
 async def readings(served, cell, seeds: list[int], seconds: float, control_seeds: int):
     """One line per seed (see the module docstring)."""
     await served.start()
     await run.warm_up(served, cell, seeds[0])
-    dims = spec.unet_dims(cell.config)
+    arch = spec.arch(cell.config)
+    dims = arch.dims(cell.config)
     limits = check.load_limits(cell.name)
     quiet = lambda msg: None  # noqa: E731
     f32 = fp8 = None
@@ -55,8 +56,8 @@ async def readings(served, cell, seeds: list[int], seconds: float, control_seeds
         p32 = check.params_f32(served.params)
         if f32 is None:
             n = cell.traffic["check_sample"]
-            f32 = reference.Sampler(cell.config, dims, p32, batch=n)
-            fp8 = reference.Sampler(cell.config, dims, p32, quant="fp8", batch=n)
+            f32 = arch.Sampler(cell.config, dims, p32, batch=n)
+            fp8 = arch.Sampler(cell.config, dims, p32, quant="fp8", batch=n)
         f32.params = fp8.params = p32
         asked = [(r.prompt, r.seed, r.tier, r.steps) for r in picked]
         wants = f32.run_many(asked)

@@ -12,7 +12,8 @@ seconds, sent over HTTP by the benchmark's own client; with ``--trace 1``
 under the JAX profiler.  After the window every request still in flight
 is waited for, the peak device memory is read, the program's state is
 freed, and a sample of the finished requests, drawn from the seed, is
-compared with the plain float32 reference (``reference.py``).
+compared with the plain float32 reference of the configuration's
+architecture (``arch/<name>.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
@@ -56,7 +57,8 @@ class RunRecord:
     """Everything a metric reader may read about one run."""
 
     cell: spec.Cell
-    dims: spec.UNetDims
+    arch: object  # the configuration's ``arch/<name>.py`` module
+    dims: object  # its ``dims`` of the configuration
     seconds: float
     setup_s: float
     reqs: list
@@ -151,8 +153,9 @@ async def serve(served: system.Served, cell: spec.Cell, seed: int, seconds: floa
 
 def record(cell: spec.Cell, out: dict, served: system.Served, seconds: float,
            peaks: dict) -> RunRecord:
+    arch = spec.arch(cell.config)
     return RunRecord(
-        cell=cell, dims=spec.unet_dims(cell.config), seconds=seconds,
+        cell=cell, arch=arch, dims=arch.dims(cell.config), seconds=seconds,
         setup_s=out.get("setup_s", 0.0), reqs=out["reqs"], t0=out["t0"], t1=out["t1"],
         stats0=out["stats0"], stats1=out["stats1"], stats_end=out["stats_end"],
         ticks=served.ticks, peaks=peaks,

@@ -2,69 +2,26 @@
 
 A cell (an entry of ``workloads``) names a configuration and a traffic mix;
 each is a JSON file of its own (``configs/<file>``, ``traffic/<name>.json``),
-and each metric is a reader ``metrics/<name>.py``.  Nothing here knows a
-cell, a configuration or a metric by name: a later cell adds files and
-entries, never code.
+and each metric is a reader ``metrics/<name>.py``.  A configuration file
+names its architecture under ``"arch"``: a module ``arch/<arch>.py`` that
+exports ``dims`` (its reading of the file), ``program_config`` (the
+program's model config), ``class_flops`` and ``kernel_calls`` (its work
+model) and ``Sampler`` (its plain reference).  Nothing here knows a cell,
+a configuration, an architecture or a metric by name: a later cell adds
+files and entries, never code.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 #: the checkout root (``benchmarks/chip/`` is two levels below it)
 ROOT = HERE.parents[1]
-
-
-@dataclasses.dataclass(frozen=True)
-class UNetDims:
-    """The published U-Net's shape, read from its diffusers ``config.json``
-    keys (the names ``configs/*.json`` keeps)."""
-
-    in_channels: int
-    out_channels: int
-    block_out_channels: tuple[int, ...]
-    layers_per_block: int
-    attn_levels: tuple[int, ...]
-    heads: int
-    cross_attention_dim: int
-    ctx_len: int
-    time_dim: int
-    groups: int
-    norm_eps: float
-    sample_size: int
-
-    @property
-    def n_levels(self) -> int:
-        return len(self.block_out_channels)
-
-    @property
-    def n_up(self) -> int:
-        return self.n_levels * (self.layers_per_block + 1)
-
-
-def unet_dims(config: dict) -> UNetDims:
-    """Diffusers keys -> :class:`UNetDims`.  SD 1.x's ``attention_head_dim``
-    is the number of heads (a naming quirk of that release), the time
-    embedding is 4x the first block width, and the text encoder's 77
-    positions set the conditioning length."""
-    down = config["down_block_types"]
-    return UNetDims(
-        in_channels=config["in_channels"],
-        out_channels=config["out_channels"],
-        block_out_channels=tuple(config["block_out_channels"]),
-        layers_per_block=config["layers_per_block"],
-        attn_levels=tuple(i for i, t in enumerate(down) if t.startswith("CrossAttn")),
-        heads=config["attention_head_dim"],
-        cross_attention_dim=config["cross_attention_dim"],
-        ctx_len=config["serving"]["text_max_length"],
-        time_dim=4 * config["block_out_channels"][0],
-        groups=config["norm_num_groups"],
-        norm_eps=config["norm_eps"],
-        sample_size=config["sample_size"],
-    )
 
 
 def load_benchmark() -> dict:
@@ -97,6 +54,7 @@ def load_cell(name: str) -> Cell:
     (cfg_entry,) = [c for c in bench["configs"] if c["name"] == w["config"]]
     with open(ROOT / cfg_entry["file"]) as f:
         config = json.load(f)
+    arch(config).dims(config)  # refuse a configuration its module would misread
     with open(HERE / "traffic" / f"{w['traffic']}.json") as f:
         traffic = json.load(f)
     return Cell(
@@ -115,9 +73,28 @@ def load_json(relpath: str) -> dict:
 
 
 def load_module(kind: str, name: str):
-    """``<kind>/<name>.py`` as a module (names may hold dots)."""
+    """``<kind>/<name>.py`` as a module (names may hold dots), registered in
+    ``sys.modules`` as it runs, as dataclasses and pickling expect."""
     path = HERE / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"chipbench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def arch(config: dict):
+    """The architecture module a configuration file names under ``"arch"``."""
+    name = config.get("arch")
+    if not isinstance(name, str) or not name.isidentifier() \
+            or not (HERE / "arch" / f"{name}.py").is_file():
+        known = sorted(p.stem for p in (HERE / "arch").glob("*.py"))
+        raise ValueError(f"configuration {config.get('name')!r}: key \"arch\" is {name!r}; "
+                         f"it must name a module arch/<arch>.py, one of {known}")
+    return _arch(name)
+
+
+@functools.cache
+def _arch(name: str):
+    """One module object per architecture, so that its classes stay one."""
+    return load_module("arch", name)

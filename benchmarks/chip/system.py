@@ -92,31 +92,6 @@ def device_stamp(chips: int, require_tpu: bool = True) -> dict:
     return info
 
 
-def unet_config(config: dict):
-    """The program's ``UNetConfig`` for the configuration file."""
-    from repro.common.types import UNetConfig
-
-    d = spec.unet_dims(config)
-    base = d.block_out_channels[0]
-    return UNetConfig(
-        name=config["name"],
-        in_channels=d.in_channels,
-        out_channels=d.out_channels,
-        base_channels=base,
-        channel_mult=tuple(c // base for c in d.block_out_channels),
-        n_res_blocks=d.layers_per_block,
-        attn_levels=d.attn_levels,
-        n_heads=d.heads,
-        tf_depth=1,
-        ctx_dim=d.cross_attention_dim,
-        ctx_len=d.ctx_len,
-        time_dim=d.time_dim,
-        groups=d.groups,
-        latent_size=d.sample_size,
-        dtype=config["serving"]["weight_dtype"],
-    )
-
-
 def make_weights(ucfg, seed: int):
     """Random weights in the served parameter tree and dtypes, made on the
     device by one jitted call from ``seed``: one stream of standard
@@ -177,7 +152,7 @@ class Served:
 
         s = config["serving"]
         sched = config["scheduler"]
-        self.ucfg = unet_config(config)
+        self.ucfg = spec.arch(config).program_config(config)
         self.dcfg = DiffusionConfig(
             timesteps_train=sched["num_train_timesteps"], timesteps_sample=s["max_steps"],
             scheduler="pndm", beta_start=sched["beta_start"], beta_end=sched["beta_end"],

@@ -12,7 +12,7 @@ import json
 import numpy as np
 import pytest
 
-from benchmarks.chip import check, reference, run, spec, system
+from benchmarks.chip import check, run, spec, system
 
 CELL = "sd_v14.tiers-steady"
 
@@ -59,8 +59,8 @@ def test_sound_run_is_correct_and_the_control_is_not(isolated, monkeypatch):
 
     # the control: the reference at float8, put in the program's place
     cell, rec = kept["cell"], kept["rec"]
-    control = reference.Sampler(cell.config, rec.dims, check.params_f32(kept["params"]),
-                                quant="fp8")
+    control = spec.arch(cell.config).Sampler(cell.config, rec.dims,
+                                             check.params_f32(kept["params"]), quant="fp8")
     latents = {}
     for r in check.sample(rec.window_reqs(), cell.traffic["check_sample"], kept["seed"]):
         latents[r.rid] = control.run(r.prompt, r.seed, r.tier, r.steps)

@@ -13,7 +13,6 @@ import pytest
 
 from benchmarks.chip import check, spec, system, trace
 from benchmarks.chip.test_correctness import toy_run
-from benchmarks.chip.work import unet
 
 NAMES = ("lane_wait.steady", "micro_step_mfu.steady", "drain_gap_share.steady",
          "host_ms_per_tick.steady")
@@ -71,7 +70,7 @@ def test_readers_return_numbers_on_the_toy_run(rec):
     assert wait + util <= 100 + 1e-9
 
     s = rec.cell.config["serving"]
-    per_row = unet.class_flops(rec.dims, s["l_sketch"], s["l_refine"])
+    per_row = spec.arch(rec.cell.config).class_flops(rec.dims, s["l_sketch"], s["l_refine"])
     t0, t1 = rec.stats0["ticks_by_class"], rec.stats1["ticks_by_class"]
     assert sum(t1[c] - t0[c] for c in per_row) == ticks
     flops = sum(2 * lanes * per_row[c] * (t1[c] - t0[c]) for c in per_row)

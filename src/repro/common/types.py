@@ -165,18 +165,59 @@ class UNetConfig:
     channel_mult: Tuple[int, ...] = (1, 2, 4, 4)
     n_res_blocks: int = 2
     attn_levels: Tuple[int, ...] = (0, 1, 2)  # levels with transformer blocks
-    n_heads: int = 8
-    tf_depth: int = 1  # transformer blocks per attention site
+    n_heads: int = 8  # attention heads at every level (``level_heads`` overrides)
+    #: BasicTransformerBlocks in one attention site (one Transformer2D) at
+    #: every level and in the mid block (``level_depths`` overrides)
+    tf_depth: int = 1
     ctx_dim: int = 768  # text-conditioning width
     ctx_len: int = 77
     time_dim: int = 1280
     groups: int = 32
     latent_size: int = 64  # spatial size of the latent
     dtype: str = "float32"
+    #: heads and transformer depth per level (None: ``n_heads`` /
+    #: ``tf_depth`` everywhere); the mid block takes the last level's, as
+    #: diffusers' ``UNet2DConditionModel`` does
+    level_heads: Tuple[int, ...] | None = None
+    level_depths: Tuple[int, ...] | None = None
+    #: SDXL's ``text_time`` added conditioning, present where ``pooled_dim``
+    #: is: a pooled text embedding ``pooled_dim`` wide and the ``time_ids``
+    #: (original size, crop origin, target size), each embedded 256 wide,
+    #: through a 2-layer MLP added to the time embedding
+    pooled_dim: int = 0
+    time_ids: Tuple[int, ...] = ()
+
+    def __post_init__(self):
+        for name in ("level_heads", "level_depths"):
+            v = getattr(self, name)
+            if v is not None and len(v) != self.n_levels:
+                raise ValueError(f"{name} has {len(v)} entries for {self.n_levels} levels")
+        if bool(self.pooled_dim) != bool(self.time_ids):
+            raise ValueError("the text_time conditioning needs pooled_dim and time_ids")
 
     @property
     def n_levels(self) -> int:
         return len(self.channel_mult)
+
+    def heads_at(self, lvl: int) -> int:
+        return self.n_heads if self.level_heads is None else self.level_heads[lvl]
+
+    def depth_at(self, lvl: int) -> int:
+        return self.tf_depth if self.level_depths is None else self.level_depths[lvl]
+
+    @property
+    def mid_heads(self) -> int:
+        return self.heads_at(self.n_levels - 1)
+
+    @property
+    def mid_tf_depth(self) -> int:
+        return self.depth_at(self.n_levels - 1)
+
+    @property
+    def added_dim(self) -> int:
+        """Width of one row's added conditioning: the pooled embedding, then
+        the time ids (0 without one)."""
+        return self.pooled_dim + len(self.time_ids)
 
     @property
     def n_skip_blocks(self) -> int:

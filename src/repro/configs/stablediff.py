@@ -1,10 +1,13 @@
 """StableDiff U-Net configs — the paper's own targets (Sec. VI-A).
 
 * sd_v14 / sd_v21: latent 64x64 (512x512 images), 860M-class U-Net.
-* sd_xl: latent 128x128 (1024x1024 images); the XL block layout
-  (3 levels, deeper transformer stacks, 2048-wide conditioning) is
-  captured structurally with tf_depth=2 (full XL uses per-level depths
-  [0,2,10]; deviation noted — MAC profile shape is preserved).
+* sd_xl: the SDXL base 1.0 U-Net (stabilityai/stable-diffusion-xl-base-1.0,
+  ``unet/config.json``): latent 128x128 (1024x1024 images), 3 levels
+  320/640/1280 with attention at levels 1-2, heads 10 and 20 of 64, one
+  Transformer2D per site of 2 and 10 BasicTransformerBlocks (mid: 10),
+  2048-wide conditioning, and the ``text_time`` added conditioning (a
+  1280-wide pooled embedding and the six size/crop ids of a 1024x1024
+  generation).
 * TOY: a trainable-on-CPU latent-diffusion model with the same topology,
   used by the end-to-end example and the PAS quality experiments.
 """
@@ -46,13 +49,15 @@ SD_XL = UNetConfig(
     channel_mult=(1, 2, 4),
     n_res_blocks=2,
     attn_levels=(1, 2),
-    n_heads=10,
-    tf_depth=2,
+    level_heads=(5, 10, 20),
+    level_depths=(1, 2, 10),
     ctx_dim=2048,
     ctx_len=77,
     time_dim=1280,
     latent_size=128,
     dtype="bfloat16",
+    pooled_dim=1280,
+    time_ids=(1024, 1024, 0, 0, 1024, 1024),  # original size, crop, target size
 )
 
 # ~100M-parameter member of the family for the end-to-end training example

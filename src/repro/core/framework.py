@@ -30,9 +30,13 @@ def _conv_macs(l: int, cin: int, cout: int, k: int) -> int:
     return l * cin * cout * k * k
 
 
-def _tf_macs(l: int, c: int, ctx_len: int, ctx_dim: int) -> int:
-    macs = 2 * _conv_macs(l, c, c, 1)  # proj in/out
-    macs += 4 * l * c * c  # self qkvo
+def _tf_macs(l: int, c: int, ctx_len: int, ctx_dim: int, depth: int = 1) -> int:
+    """One Transformer2D site: proj in/out once, ``depth`` blocks."""
+    return 2 * _conv_macs(l, c, c, 1) + depth * _block_macs(l, c, ctx_len, ctx_dim)
+
+
+def _block_macs(l: int, c: int, ctx_len: int, ctx_dim: int) -> int:
+    macs = 4 * l * c * c  # self qkvo
     macs += 2 * l * l * c  # self attention scores + values
     macs += l * c * c + 2 * ctx_len * ctx_dim * c + l * c * c  # cross q, kv, o
     macs += 2 * l * ctx_len * c  # cross attention
@@ -74,14 +78,15 @@ def unet_mac_breakdown(cfg: UNetConfig) -> MACBreakdown:
         for _ in range(cfg.n_res_blocks):
             m = _res_macs(cur, ch, cout)
             if lvl in cfg.attn_levels:
-                m += cfg.tf_depth * _tf_macs(cur, cout, cfg.ctx_len, cfg.ctx_dim)
+                m += _tf_macs(cur, cout, cfg.ctx_len, cfg.ctx_dim, cfg.depth_at(lvl))
             down.append(m)
             ch = cout
         if lvl != cfg.n_levels - 1:
             down.append(_conv_macs(cur // 4, ch, ch, 3))
             cur //= 4
 
-    mid = 2 * _res_macs(cur, ch, ch) + cfg.tf_depth * _tf_macs(cur, ch, cfg.ctx_len, cfg.ctx_dim)
+    mid = 2 * _res_macs(cur, ch, ch) + _tf_macs(cur, ch, cfg.ctx_len, cfg.ctx_dim,
+                                               cfg.mid_tf_depth)
 
     # up path: replay channel bookkeeping of init_unet
     skip_ch = [cfg.base_channels]
@@ -102,7 +107,7 @@ def unet_mac_breakdown(cfg: UNetConfig) -> MACBreakdown:
             sc = skip_ch.pop()
             m = _res_macs(cur_l, ch_up + sc, cout)
             if lvl in cfg.attn_levels:
-                m += cfg.tf_depth * _tf_macs(cur_l, cout, cfg.ctx_len, cfg.ctx_dim)
+                m += _tf_macs(cur_l, cout, cfg.ctx_len, cfg.ctx_dim, cfg.depth_at(lvl))
             if i == cfg.n_res_blocks and lvl != 0:
                 m += _conv_macs(cur_l * 4, cout, cout, 3)
             up.append(m)

@@ -19,6 +19,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.common.types import DiffusionConfig, PASPlan, UNetConfig
 from repro.models import diffusion as D
@@ -44,6 +45,15 @@ def _entry_steps(ucfg: UNetConfig, plan: PASPlan) -> tuple[int, int]:
     return n_up - plan.l_sketch, n_up - plan.l_refine
 
 
+def uncond_added(ucfg: UNetConfig, added):
+    """The unconditional rows' added conditioning for conditional rows'
+    ``added`` [..., added_dim] (numpy or jax): the pooled embedding zeroed
+    (SDXL's ``force_zeros_for_empty_prompt``), the time ids kept."""
+    keep = np.concatenate([np.zeros(ucfg.pooled_dim, np.float32),
+                           np.ones(len(ucfg.time_ids), np.float32)])
+    return added * keep
+
+
 def cfg_unet_step(
     ucfg: UNetConfig,
     params: Params,
@@ -56,6 +66,7 @@ def cfg_unet_step(
     entry_feat: jax.Array | None = None,  # [2B, ...] cached main-branch feature
     capture: tuple[int, ...] = (),
     backend=None,  # KernelBackend instance or name; None = "xla"
+    added2: jax.Array | None = None,  # [2B, added_dim] = [cond; uncond]
 ) -> tuple[jax.Array, dict[int, jax.Array]]:
     """One classifier-free-guided U-Net invocation on the CFG-doubled batch.
 
@@ -63,7 +74,9 @@ def cfg_unet_step(
     serving engine's micro-step (per-lane ``t`` vector).  Returns the guided
     eps prediction [B, L, C] and the captured main-branch features in the
     [2B, ...] cond/uncond-stacked layout.  ``backend`` is forwarded to
-    :func:`repro.models.unet.unet_apply` (the kernel-backend chokepoint).
+    :func:`repro.models.unet.unet_apply` (the kernel-backend chokepoint),
+    as is ``added2``, the CFG-doubled added conditioning of a U-Net that
+    has one (SDXL's ``text_time``).
     """
     b = x.shape[0]
     x2 = jnp.concatenate([x, x], axis=0)
@@ -72,7 +85,7 @@ def cfg_unet_step(
     eps2, cap = U.unet_apply(
         ucfg, params, x2, t2, ctx2,
         entry_step=entry_step, entry_feat=entry_feat, capture_steps=capture,
-        backend=backend,
+        backend=backend, added=added2,
     )
     e_c, e_u = jnp.split(eps2, 2, axis=0)
     return e_u + guidance * (e_c - e_u), cap
@@ -130,6 +143,7 @@ def pas_denoise_scheduled(
     x_init: jax.Array | None = None,  # [B, L, C] known latent under the mask
     noise0: jax.Array | None = None,  # [B, L, C] fixed noise for the known region
     backend=None,  # kernel backend forwarded to every U-Net call
+    added: jax.Array | None = None,  # [B, added_dim] conditional rows' added conditioning
 ) -> jax.Array:
     """Straight-line PAS sampling over an *explicit* timestep schedule.
 
@@ -149,6 +163,8 @@ def pas_denoise_scheduled(
 
     ``ts=None`` with no mask is exactly the :func:`pas_denoise` loop (same
     math; the scan carries two extra — constant — leaves when masked).
+    ``added`` is the added conditioning of a U-Net that has one; the
+    unconditional rows get :func:`uncond_added` of it.
     """
     sched = D.make_schedule(dcfg)
     if ts is None:
@@ -176,12 +192,14 @@ def pas_denoise_scheduled(
     e_sk, e_rf = _entry_steps(ucfg, plan)
 
     ctx2 = jnp.concatenate([ctx_cond, ctx_uncond], axis=0)
+    added2 = None if added is None else jnp.concatenate(
+        [added, uncond_added(ucfg, added)], axis=0)
 
     def run_unet(x, t, entry_step, entry_feat, capture):
         return cfg_unet_step(
             ucfg, params, guidance, x, t, ctx2,
             entry_step=entry_step, entry_feat=entry_feat, capture=capture,
-            backend=backend,
+            backend=backend, added2=added2,
         )
 
     f_sk0 = jnp.zeros(_feat_shape(ucfg, e_sk, b2), x_t.dtype)

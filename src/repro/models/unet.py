@@ -1,8 +1,15 @@
 """StableDiff U-Net in JAX with block-granular partial execution for PAS.
 
 Topology follows SD v1.x/v2.x/XL (configurable via ``UNetConfig``):
-``conv_in`` + per-level [ResBlock(+Transformer)] stacks with downsamples,
-a middle block, and an up path consuming skip connections in reverse.
+``conv_in`` + per-level [ResBlock(+Transformer2D)] stacks with downsamples,
+a middle block, and an up path consuming skip connections in reverse.  An
+attention site is one Transformer2D: a group norm and ``proj_in``, the
+level's depth of BasicTransformerBlocks (self-attention, cross-attention,
+GEGLU, each behind a layer norm), ``proj_out`` and the residual; heads and
+depth are per level (SDXL: heads 5/10/20 of 64, depths 1/2/10).  SDXL's
+``text_time`` added conditioning (a pooled text embedding and six
+embedded size/crop ids through a 2-layer MLP) is added to the time
+embedding.
 
 The paper's Fig. 3/5 block indexing: the down path produces ``n_skip``
 skip tensors (12 for SD v1.4); partial execution with budget ``l`` runs
@@ -174,6 +181,7 @@ def apply_res(p: Params, x: jax.Array, temb: jax.Array, hw, groups: int, backend
 
 
 def init_tf(key, c: int, n_heads: int, ctx_dim: int, dtype) -> Params:
+    """A depth-1 Transformer2D: its norm and projections with one block."""
     ks = jax.random.split(key, 12)
     return {
         "gn": init_gn(c),
@@ -195,6 +203,22 @@ def init_tf(key, c: int, n_heads: int, ctx_dim: int, dtype) -> Params:
     }
 
 
+def init_site(key, c: int, n_heads: int, ctx_dim: int, depth: int, dtype) -> list[Params]:
+    """One attention site, a Transformer2D of ``depth`` BasicTransformerBlocks,
+    as a list of block dicts: the first also holds ``gn`` and ``proj_in``,
+    the last ``proj_out``.  At depth 1 the one dict is :func:`init_tf`'s,
+    drawn from ``key`` itself."""
+    if depth == 1:
+        return [init_tf(key, c, n_heads, ctx_dim, dtype)]
+    blocks = [init_tf(k, c, n_heads, ctx_dim, dtype) for k in jax.random.split(key, depth)]
+    for i, blk in enumerate(blocks):
+        if i:
+            del blk["gn"], blk["proj_in"]
+        if i < depth - 1:
+            del blk["proj_out"]
+    return blocks
+
+
 def _mha(q, k, v, o_proj, n_heads: int):
     bsz, lq, c = q.shape
     lk = k.shape[1]
@@ -208,14 +232,24 @@ def _mha(q, k, v, o_proj, n_heads: int):
     return out @ o_proj
 
 
-def apply_tf(
-    p: Params, x: jax.Array, ctx: jax.Array, hw, n_heads: int, groups: int, backend=None
+def apply_site(
+    blocks: Sequence[Params], x: jax.Array, ctx: jax.Array, hw, n_heads: int, groups: int,
+    backend=None,
 ) -> jax.Array:
+    """One Transformer2D (:func:`init_site`'s list): norm and ``proj_in``,
+    every block in turn, ``proj_out``, residual."""
     bk = _resolve_backend(backend)
-    res0 = x
-    h = bk.group_norm(x, p["gn"], groups)
-    h = bk.conv(p["proj_in"]["w"], p["proj_in"]["b"], h, hw, 1)
+    first, last = blocks[0], blocks[-1]
+    h = bk.group_norm(x, first["gn"], groups)
+    h = bk.conv(first["proj_in"]["w"], first["proj_in"]["b"], h, hw, 1)
+    for p in blocks:
+        h = _basic_block(p, h, ctx, n_heads, bk)
+    h = bk.conv(last["proj_out"]["w"], last["proj_out"]["b"], h, hw, 1)
+    return h + x
 
+
+def _basic_block(p: Params, h: jax.Array, ctx: jax.Array, n_heads: int, bk) -> jax.Array:
+    """LN, self-attention, LN, cross-attention, LN, GEGLU, each residual."""
     z = layer_norm(h, p["ln1"])
     h = h + bk.attention(z @ p["self_q"], z @ p["self_k"], z @ p["self_v"], p["self_o"], n_heads)
     z = layer_norm(h, p["ln2"])
@@ -226,10 +260,39 @@ def apply_tf(
     ff = z @ p["ff_in"]
     gate, val = jnp.split(ff, 2, axis=-1)
     gelu = lambda t: t * jax.nn.sigmoid(1.702 * t)  # paper's sigmoid GELU
-    h = h + (gelu(gate) * val) @ p["ff_out"]
+    return h + (gelu(gate) * val) @ p["ff_out"]
 
-    h = bk.conv(p["proj_out"]["w"], p["proj_out"]["b"], h, hw, 1)
-    return h + res0
+
+# ---------------------------------------------------------------------------
+# SDXL's text_time added conditioning
+# ---------------------------------------------------------------------------
+
+
+#: width of each time id's sinusoid (diffusers' ``addition_time_embed_dim``)
+TIME_ID_DIM = 256
+
+
+def init_add_embedding(key, cfg: UNetConfig, dtype) -> Params:
+    """``add_embedding``: (pooled ++ embedded time ids) -> time_dim -> time_dim."""
+    k1, k2 = jax.random.split(key)
+    d_in = cfg.pooled_dim + len(cfg.time_ids) * TIME_ID_DIM
+    return {
+        "w1": _dense_init(k1, (d_in, cfg.time_dim), dtype),
+        "b1": jnp.zeros((cfg.time_dim,), dtype),
+        "w2": _dense_init(k2, (cfg.time_dim, cfg.time_dim), dtype),
+        "b2": jnp.zeros((cfg.time_dim,), dtype),
+    }
+
+
+def added_embedding(cfg: UNetConfig, p: Params, added: jax.Array) -> jax.Array:
+    """``added`` [B, pooled_dim + n_ids] (the pooled text embedding, then the
+    time ids) -> [B, time_dim]: each id embedded ``TIME_ID_DIM`` wide (cos
+    first), concatenated after the pooled embedding, then the MLP."""
+    b = added.shape[0]
+    pooled, ids = added[:, : cfg.pooled_dim], added[:, cfg.pooled_dim :]
+    emb = timestep_embedding(ids.reshape(-1), TIME_ID_DIM).reshape(b, -1)
+    z = jnp.concatenate([pooled, emb.astype(pooled.dtype)], axis=-1)
+    return jax.nn.silu(z @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
 
 
 # ---------------------------------------------------------------------------
@@ -268,10 +331,8 @@ def init_unet(key, cfg: UNetConfig) -> Params:
         for _ in range(cfg.n_res_blocks):
             blk = {"res": init_res(next(ks), ch, cout, tdim, cfg.groups, dtype)}
             if lvl in cfg.attn_levels:
-                blk["tf"] = [
-                    init_tf(next(ks), cout, cfg.n_heads, cfg.ctx_dim, dtype)
-                    for _ in range(cfg.tf_depth)
-                ]
+                blk["tf"] = init_site(next(ks), cout, cfg.heads_at(lvl), cfg.ctx_dim,
+                                      cfg.depth_at(lvl), dtype)
             params["down"].append(blk)
             ch = cout
         if lvl != cfg.n_levels - 1:
@@ -280,10 +341,7 @@ def init_unet(key, cfg: UNetConfig) -> Params:
     # middle
     params["mid"] = {
         "res1": init_res(next(ks), ch, ch, tdim, cfg.groups, dtype),
-        "tf": [
-            init_tf(next(ks), ch, cfg.n_heads, cfg.ctx_dim, dtype)
-            for _ in range(cfg.tf_depth)
-        ],
+        "tf": init_site(next(ks), ch, cfg.mid_heads, cfg.ctx_dim, cfg.mid_tf_depth, dtype),
         "res2": init_res(next(ks), ch, ch, tdim, cfg.groups, dtype),
     }
 
@@ -304,15 +362,33 @@ def init_unet(key, cfg: UNetConfig) -> Params:
             sc = skip_ch.pop()
             blk = {"res": init_res(next(ks), ch_up + sc, cout, tdim, cfg.groups, dtype)}
             if lvl in cfg.attn_levels:
-                blk["tf"] = [
-                    init_tf(next(ks), cout, cfg.n_heads, cfg.ctx_dim, dtype)
-                    for _ in range(cfg.tf_depth)
-                ]
+                blk["tf"] = init_site(next(ks), cout, cfg.heads_at(lvl), cfg.ctx_dim,
+                                      cfg.depth_at(lvl), dtype)
             if i == cfg.n_res_blocks and lvl != 0:
                 blk["upsample"] = init_conv(next(ks), 3, cout, cout, dtype)
             params["up"].append(blk)
             ch_up = cout
+    if cfg.added_dim:
+        params["add_embedding"] = init_add_embedding(next(ks), cfg, dtype)
     return params
+
+
+def tf_blocks(cfg: UNetConfig, entry_step: int = 0) -> int:
+    """BasicTransformerBlocks one row's pass runs: FULL (``entry_step ==
+    0``) or partial, entering up-step ``entry_step`` (sd_v14: 16 FULL)."""
+    n_up = n_up_steps(cfg)
+    n_skips = n_up - entry_step
+    blocks, skips = 0, 1  # conv_in's output is the first skip
+    for lvl, has_attn, _ in _down_plan(cfg):
+        if entry_step and skips >= n_skips:
+            break
+        blocks += cfg.depth_at(lvl) if has_attn else 0
+        skips += 1
+    if entry_step == 0:
+        blocks += cfg.mid_tf_depth
+    for lvl, has_attn, _ in _up_plan(cfg)[entry_step:]:
+        blocks += cfg.depth_at(lvl) if has_attn else 0
+    return blocks
 
 
 def n_up_steps(cfg: UNetConfig) -> int:
@@ -357,6 +433,7 @@ def unet_apply(
     entry_feat: jax.Array | None = None,  # cached main-branch feature
     capture_steps: Sequence[int] = (),
     backend=None,  # KernelBackend instance or name; None = "xla"
+    added: jax.Array | None = None,  # [B, cfg.added_dim] text_time conditioning
 ) -> tuple[jax.Array, dict[int, jax.Array]]:
     """Full or partial U-Net forward.
 
@@ -369,6 +446,9 @@ def unet_apply(
     conv / group-norm / attention call routes through; the default XLA
     backend traces the identical program as the pre-dispatch inline code.
 
+    ``added`` is the ``text_time`` conditioning (see
+    :func:`added_embedding`), required exactly when ``cfg`` has one.
+
     Returns (eps_prediction, {captured step -> main-branch feature}).
     """
     bk = _resolve_backend(backend)
@@ -379,6 +459,11 @@ def unet_apply(
     temb = timestep_embedding(t, cfg.base_channels).astype(x.dtype)
     tm = params["time_mlp"]
     temb = jax.nn.silu(temb @ tm["w1"] + tm["b1"]) @ tm["w2"] + tm["b2"]
+    if (added is None) != (not cfg.added_dim):
+        raise ValueError(f"{cfg.name} takes {'no' if not cfg.added_dim else 'an'} added "
+                         f"conditioning; {'none' if added is None else 'one'} was given")
+    if added is not None:
+        temb = temb + added_embedding(cfg, params["add_embedding"], added).astype(temb.dtype)
 
     up_plan = _up_plan(cfg)
     n_up = len(up_plan)
@@ -400,8 +485,7 @@ def unet_apply(
         else:
             h = apply_res(entry["res"], h, temb, hw, groups, backend=bk)
             if has_attn:
-                for tfp in entry["tf"]:
-                    h = apply_tf(tfp, h, ctx, hw, cfg.n_heads, groups, backend=bk)
+                h = apply_site(entry["tf"], h, ctx, hw, cfg.heads_at(lvl), groups, backend=bk)
         skips.append(h)
         hws.append(hw)
 
@@ -411,8 +495,7 @@ def unet_apply(
     if entry_step == 0:
         m = params["mid"]
         h = apply_res(m["res1"], h, temb, hw, groups, backend=bk)
-        for tfp in m["tf"]:
-            h = apply_tf(tfp, h, ctx, hw, cfg.n_heads, groups, backend=bk)
+        h = apply_site(m["tf"], h, ctx, hw, cfg.mid_heads, groups, backend=bk)
         h = apply_res(m["res2"], h, temb, hw, groups, backend=bk)
     else:
         assert entry_feat is not None, "partial run needs the cached feature"
@@ -430,8 +513,7 @@ def unet_apply(
         h = apply_res(entry["res"], h, temb, hw, groups, backend=bk)
         lvl, has_attn, up_after = up_plan[step]
         if has_attn:
-            for tfp in entry["tf"]:
-                h = apply_tf(tfp, h, ctx, hw, cfg.n_heads, groups, backend=bk)
+            h = apply_site(entry["tf"], h, ctx, hw, cfg.heads_at(lvl), groups, backend=bk)
         if up_after:
             h, hw = _upsample2x(h, hw)
             h = bk.conv(entry["upsample"]["w"], entry["upsample"]["b"], h, hw, 3)

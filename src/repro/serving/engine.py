@@ -81,6 +81,9 @@ class GenRequest:
     mask: np.ndarray | None = None
     #: untruncated schedule length; None = ``timesteps`` (no truncation)
     base_timesteps: int | None = None
+    #: [added_dim] added conditioning of a U-Net that has one (SDXL's
+    #: ``text_time``: the pooled text embedding, then the time ids)
+    added: np.ndarray | None = None
 
     _lane_plan: LN.LanePlan | None = dataclasses.field(default=None, repr=False)
     _sig: np.ndarray | None = dataclasses.field(default=None, repr=False)
@@ -235,6 +238,8 @@ class DiffusionEngine:
         self.e_rf = n_up - config.l_refine
         self.scheduler = scheduler if scheduler is not None else FIFOScheduler()
         self.metrics = ServingMetrics()
+        #: BasicTransformerBlocks one row's pass runs, by branch class
+        self._tf_blocks = tuple(U.tf_blocks(ucfg, e) for e in (0, self.e_sk, self.e_rf))
 
         self._build_device_state(params)  # sets self.cache/_state/_micro/_admit
         if hasattr(self.scheduler, "attach_cache"):
@@ -336,6 +341,11 @@ class DiffusionEngine:
             req._entry = np.asarray(entry)
         else:
             req._entry = req.noise
+        want = (self.ucfg.added_dim,) if self.ucfg.added_dim else None
+        got = None if req.added is None else np.shape(req.added)
+        if got != want:
+            raise ValueError(f"{self.ucfg.name} wants added conditioning of shape {want}, "
+                             f"the request has {got}")
         req._sig = prompt_signature(req.ctx)
         self.metrics.record_submission(req.quality_tier)
         self.scheduler.add(req)
@@ -474,11 +484,20 @@ class DiffusionEngine:
                 jnp.int32(lp.n_steps),
                 jnp.asarray(lp.thr),
                 mask, x_init, noise0,
+                **self._admit_added(req),
             )
         self._lane_req[lane] = req
         self._lane_step[lane] = 0
         self._lane_admit_s[lane] = now_s
         self._stall[lane] = 0
+
+    def _admit_added(self, req: GenRequest) -> dict:
+        """The admit program's ``added2`` [2, added_dim] (the request's added
+        conditioning and its unconditional row), or nothing."""
+        if req.added is None:
+            return {}
+        added = np.asarray(req.added, np.float32)
+        return {"added2": jnp.asarray(np.stack([added, SM.uncond_added(self.ucfg, added)]))}
 
     def _probe_eligible(self, req: GenRequest, lane: int, planned: int) -> bool:
         """Whether a lane's next planned step may be served from the cache.
@@ -618,6 +637,7 @@ class DiffusionEngine:
             n_refine=n_adv if b_star == SM.REFINE else 0,
             n_demoted=n_demoted, n_demoted_refine=n_demoted_rf,
             dispatches=len(batches), unet_rows=2 * self._k * len(batches),
+            tf_block_rows=2 * self._k * len(batches) * self._tf_blocks[b_star],
         )
 
         return self._retire(active, now_s, clock)
@@ -993,6 +1013,7 @@ class ShardedDiffusionEngine(DiffusionEngine):
             n_full=n_full, n_sketch=n_sketch, n_refine=n_refine,
             n_demoted=n_demoted, n_demoted_refine=n_demoted_rf,
             shard_active=shard_active, dispatches=1, unet_rows=2 * n,
+            tf_block_rows=2 * self.lanes_per_shard * sum(self._tf_blocks[b] for b in b_arr),
         )
 
         return self._retire(active, now_s, clock)

@@ -49,6 +49,8 @@ Endpoints (HTTP/1.1, ``Connection: close``):
     the per-quality-tier request mix (``quality_mix``), the active kernel
     backend (``kernels``), micro-steps per branch class
     (``ticks_by_class``), held lane slots (``lane_slots_active``), the
+    U-Net rows computed (``unet_rows``) and those rows times the
+    transformer blocks their pass runs (``tf_block_rows``), the
     host spans of the engine and driver (``spans``: ``{name: {n, s}}``)
     and the host gap after each retirement sync (``drain_gap_s``,
     ``drains``), so mixed-quality streams are observable without the
@@ -240,6 +242,12 @@ class RequestFactory:
         rng = np.random.default_rng((spec.seed, mix))
         ctx = rng.normal(size=(self.ucfg.ctx_len, self.ucfg.ctx_dim)).astype(np.float32) * 0.2
         noise = rng.normal(size=(L, self.ucfg.in_channels)).astype(np.float32)
+        added = None
+        if self.ucfg.added_dim:
+            # the stand-in for a text encoder's pooled embedding, drawn next
+            # from the same stream, then the generation's time ids
+            pooled = rng.normal(size=(self.ucfg.pooled_dim,)).astype(np.float32)
+            added = np.concatenate([pooled, np.asarray(self.ucfg.time_ids, np.float32)])
 
         if spec.task == "variations":
             # variant 0 reuses the txt2img noise; later variants draw
@@ -261,6 +269,7 @@ class RequestFactory:
                     plan=pol.plan,
                     allow_cache=spec.allow_cache,
                     policy=pol,
+                    added=added,
                 )
                 for rid, nz in zip(rids, noises)
             ]
@@ -287,6 +296,7 @@ class RequestFactory:
             init_latent=init_latent,
             mask=mask,
             base_timesteps=spec.base_timesteps,
+            added=added,
         )
         return [req], None, spec
 

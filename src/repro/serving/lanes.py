@@ -27,6 +27,7 @@ Layout notes
 """
 from __future__ import annotations
 
+import collections
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -69,6 +70,22 @@ class LaneState(NamedTuple):
 
     def active_mask(self) -> jax.Array:
         return self.step < self.n_steps
+
+
+def _with_added(base: type, leaf: str, doc: str) -> type:
+    """``base`` with one more leaf, ``leaf``, last: the lane state of a U-Net
+    with an added conditioning, so that every other U-Net keeps ``base``'s
+    pytree exactly."""
+    fields = collections.namedtuple(f"_Added{base.__name__}", base._fields + (leaf,))
+    return type(f"Added{base.__name__}", (fields,), {
+        "__slots__": (), "__doc__": doc,
+        "n_lanes": base.n_lanes, "active_mask": base.active_mask,
+    })
+
+
+#: :class:`LaneState` plus ``add2`` [2N, added_dim], the CFG-doubled added
+#: conditioning (SDXL's ``text_time``; uncond rows keep the time ids only)
+AddedLaneState = _with_added(LaneState, "add2", "LaneState with the added conditioning.")
 
 
 class LanePlan(NamedTuple):
@@ -138,11 +155,14 @@ def init_lanes(
     e_rf: int,
     dtype=jnp.float32,
 ) -> LaneState:
-    """All-empty lane state (every lane has ``n_steps == 0``)."""
+    """All-empty lane state (every lane has ``n_steps == 0``); an
+    :data:`AddedLaneState` for a U-Net with an added conditioning."""
     L = ucfg.latent_size**2
     c = ucfg.in_channels
     z = jnp.zeros
-    return LaneState(
+    added = {"add2": z((2 * n_lanes, ucfg.added_dim), dtype)} if ucfg.added_dim else {}
+    return (AddedLaneState if added else LaneState)(
+        **added,
         x=z((n_lanes, L, c), dtype),
         ets=z((n_lanes, 4, L, c), dtype),
         n_ets=z((n_lanes,), jnp.int32),
@@ -174,10 +194,17 @@ def admit(
     mask: jax.Array | None = None,  # [L, 1] inpaint mask; None = all-ones
     x_init: jax.Array | None = None,  # [L, C] known latent; None = zeros
     noise0: jax.Array | None = None,  # [L, C] known-region noise; None = zeros
+    added2: jax.Array | None = None,  # [2, added_dim] cond, uncond added conditioning
 ) -> LaneState:
-    """Scatter one request into an (empty) lane, resetting its sampler state."""
+    """Scatter one request into an (empty) lane, resetting its sampler state.
+    ``added2`` is required exactly when the state carries an added
+    conditioning."""
     n = state.n_lanes
-    return LaneState(
+    added = {}
+    if _has_added(state, "add2", added2):
+        added["add2"] = state.add2.at[lane].set(added2[0]).at[n + lane].set(added2[1])
+    return type(state)(
+        **added,
         x=state.x.at[lane].set(noise),
         ets=state.ets.at[lane].set(0.0),
         n_ets=state.n_ets.at[lane].set(0),
@@ -196,6 +223,16 @@ def admit(
     )
 
 
+def _has_added(state, leaf: str, added) -> bool:
+    """Whether the state carries the added conditioning ``leaf``; the
+    admitted request must bring one exactly then."""
+    carried = leaf in state._fields
+    if carried != (added is not None):
+        raise ValueError(f"the lane state {'carries' if carried else 'has no'} added "
+                         f"conditioning; the request {'has none' if added is None else 'has'}")
+    return carried
+
+
 def release(state: LaneState, lane: jax.Array) -> LaneState:
     """Mark a lane empty (retirement without immediate backfill)."""
     return state._replace(
@@ -209,7 +246,7 @@ def gather_latent(state: LaneState, lane: int) -> jax.Array:
 
 
 #: LaneState leaves in the CFG-doubled ``[2N, ...]`` layout
-_CFG_ROWS = frozenset({"f_sk", "f_rf", "ctx2"})
+_CFG_ROWS = frozenset({"f_sk", "f_rf", "ctx2", "add2"})
 #: the leaves a micro-step writes; every other leaf only changes at admission
 _STEPPED = ("x", "ets", "n_ets", "f_sk", "f_rf", "step")
 
@@ -254,9 +291,9 @@ def _gather_lanes(state: LaneState, idx: jax.Array) -> LaneState:
     """The ``K`` lanes ``idx`` as a K-lane :class:`LaneState` (their CFG
     rows ``idx`` and ``N + idx``)."""
     rows = jnp.concatenate([idx, state.n_lanes + idx])
-    return LaneState(*(
+    return type(state)(*(
         leaf[rows] if name in _CFG_ROWS else leaf[idx]
-        for name, leaf in zip(LaneState._fields, state)
+        for name, leaf in zip(state._fields, state)
     ))
 
 
@@ -350,25 +387,26 @@ def make_micro_step(
         t = take(state.ts)
         tp = take(state.t_prev)
         ctx2 = state.ctx2
+        added2 = state.add2 if "add2" in state._fields else None
 
         def full_branch(_):
             eps, cap = SM.cfg_unet_step(
                 ucfg, params, guidance, state.x, t, ctx2, capture=(e_sk, e_rf),
-                backend=bk,
+                backend=bk, added2=added2,
             )
             return eps, cap[e_sk], cap[e_rf]
 
         def sketch_branch(_):
             eps, _ = SM.cfg_unet_step(
                 ucfg, params, guidance, state.x, t, ctx2,
-                entry_step=e_sk, entry_feat=entry_sk, backend=bk,
+                entry_step=e_sk, entry_feat=entry_sk, backend=bk, added2=added2,
             )
             return eps, entry_sk, entry_rf
 
         def refine_branch(_):
             eps, _ = SM.cfg_unet_step(
                 ucfg, params, guidance, state.x, t, ctx2,
-                entry_step=e_rf, entry_feat=entry_rf, backend=bk,
+                entry_step=e_rf, entry_feat=entry_rf, backend=bk, added2=added2,
             )
             # a REFINE step never becomes the lane's feature source of
             # record: a SKETCH->REFINE demotion consumes the slot for THIS
@@ -506,6 +544,12 @@ class ShardedLaneState(NamedTuple):
         return self.step < self.n_steps
 
 
+#: :class:`ShardedLaneState` plus ``add`` [N, 2, added_dim], the added
+#: conditioning (cond, uncond) of a U-Net that has one
+AddedShardedLaneState = _with_added(ShardedLaneState, "add",
+                                    "ShardedLaneState with the added conditioning.")
+
+
 def init_sharded_lanes(
     ucfg: UNetConfig,
     n_lanes: int,
@@ -527,7 +571,9 @@ def init_sharded_lanes(
     rf = SM.feat_shape(ucfg, e_rf, 1)[1:]
     sh = lane_sharding(mesh)
     z = lambda shape, dt=dtype: jax.device_put(jnp.zeros(shape, dt), sh)
-    return ShardedLaneState(
+    added = {"add": z((n_lanes, 2, ucfg.added_dim))} if ucfg.added_dim else {}
+    return (AddedShardedLaneState if added else ShardedLaneState)(
+        **added,
         x=z((n_lanes, L, c)),
         ets=z((n_lanes, 4, L, c)),
         n_ets=z((n_lanes,), jnp.int32),
@@ -565,8 +611,12 @@ def make_sharded_admit(mesh):
         mask: jax.Array | None = None,  # [L, 1] inpaint mask; None = all-ones
         x_init: jax.Array | None = None,  # [L, C] known latent; None = zeros
         noise0: jax.Array | None = None,  # [L, C] known-region noise; None = zeros
+        added2: jax.Array | None = None,  # [2, added_dim] cond, uncond
     ) -> ShardedLaneState:
-        return ShardedLaneState(
+        added = {"add": state.add.at[lane].set(added2)} \
+            if _has_added(state, "add", added2) else {}
+        return type(state)(
+            **added,
             x=state.x.at[lane].set(noise),
             ets=state.ets.at[lane].set(0.0),
             n_ets=state.n_ets.at[lane].set(0),
@@ -672,25 +722,26 @@ def make_sharded_micro_step(
         ctx2 = jnp.concatenate([state.ctx[:, 0], state.ctx[:, 1]], axis=0)
         pair2 = lambda a: jnp.concatenate([a[:, 0], a[:, 1]], axis=0)  # [P,2,..]->[2P,..]
         unpair = lambda a: jnp.stack([a[:p], a[p:]], axis=1)  # [2P,..]->[P,2,..]
+        added2 = pair2(state.add) if "add" in state._fields else None
 
         def full_branch(_):
             eps, cap = SM.cfg_unet_step(
                 ucfg, params, guidance, state.x, t, ctx2, capture=(e_sk, e_rf),
-                backend=bk,
+                backend=bk, added2=added2,
             )
             return eps, unpair(cap[e_sk]), unpair(cap[e_rf])
 
         def sketch_branch(_):
             eps, _ = SM.cfg_unet_step(
                 ucfg, params, guidance, state.x, t, ctx2,
-                entry_step=e_sk, entry_feat=pair2(entry_sk), backend=bk,
+                entry_step=e_sk, entry_feat=pair2(entry_sk), backend=bk, added2=added2,
             )
             return eps, entry_sk, entry_rf
 
         def refine_branch(_):
             eps, _ = SM.cfg_unet_step(
                 ucfg, params, guidance, state.x, t, ctx2,
-                entry_step=e_rf, entry_feat=pair2(entry_rf), backend=bk,
+                entry_step=e_rf, entry_feat=pair2(entry_rf), backend=bk, added2=added2,
             )
             # as in the single-device micro-step: a (possibly demoted)
             # REFINE step consumes the entry features for this step only —

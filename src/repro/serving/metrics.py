@@ -54,6 +54,8 @@ class ServingMetrics:
     #: unet_rows`` is the share of computed rows thrown away
     micro_dispatches: int = 0
     unet_rows: int = 0
+    #: those rows times the BasicTransformerBlocks their class's pass runs
+    tf_block_rows: int = 0
     #: running sums behind ``mean_occupancy`` / ``mean_advance_eff``
     occupancy_sum: float = 0.0
     advance_eff_sum: float = 0.0
@@ -106,10 +108,12 @@ class ServingMetrics:
         shard_active: Sequence[int] | None = None,
         dispatches: int = 0,
         unet_rows: int = 0,
+        tf_block_rows: int = 0,
     ) -> None:
         self.micro_steps += 1
         self.micro_dispatches += dispatches
         self.unet_rows += unet_rows
+        self.tf_block_rows += tf_block_rows
         self.lane_steps_advanced += n_advanced
         self.lane_slots_active += n_active
         cls = next((c for c, n in zip(CLASSES, (n_full, n_sketch, n_refine)) if n), None)
@@ -190,6 +194,7 @@ class ServingMetrics:
             "ticks_by_class": dict(self.ticks_by_class),
             "micro_dispatches": self.micro_dispatches,
             "unet_rows": self.unet_rows,
+            "tf_block_rows": self.tf_block_rows,
             "mean_occupancy": round(self.occupancy_sum / self.micro_steps, 3)
             if self.micro_steps
             else 0.0,
